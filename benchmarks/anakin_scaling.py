@@ -57,6 +57,9 @@ def measure(n_devices: int) -> float:
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # a CPU study on placeholder devices by design: the child never
+    # reaches for an accelerator this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT.format(n=n_devices, src=src)],
         capture_output=True, text=True, timeout=600, env=env,
@@ -77,7 +80,7 @@ def main(device_counts=(1, 2, 4, 8)) -> list[str]:
         base = base or fps
         lines.append(
             f"anakin_scaling_d{n},{1e6 / fps:.3f},"
-            f"fps={fps:,.0f} rel={fps / base:.2f} per_dev={fps / n:,.0f}"
+            f"platform=cpu fps={fps:,.0f} rel={fps / base:.2f} per_dev={fps / n:,.0f}"
         )
         print(lines[-1], flush=True)
     return lines

@@ -134,6 +134,7 @@ def _spawn(mode: str, registry: str, host_id: str, *, frames: int = 0,
     if frames:
         cmd += ["--frames", str(frames)]
     env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"  # CPU workers: the parent may hold the chip
     src = os.path.join(_REPO_ROOT, "src")
     env["PYTHONPATH"] = os.pathsep.join(
         p for p in (src, env.get("PYTHONPATH")) if p

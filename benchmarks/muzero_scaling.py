@@ -52,6 +52,9 @@ def measure(n_devices: int, frames: int = 3_000) -> float:
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # a CPU study on placeholder devices by design: the child never
+    # reaches for an accelerator this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT.format(n=n_devices, frames=frames,
                                               src=src)],
@@ -69,7 +72,7 @@ def main(device_counts=(4, 8)) -> list[str]:
     lines = []
     for n in device_counts:
         fps = measure(n)
-        lines.append(f"muzero_scaling_d{n},{1e6 / fps:.3f},fps={fps:,.0f}")
+        lines.append(f"muzero_scaling_d{n},{1e6 / fps:.3f},platform=cpu fps={fps:,.0f}")
         print(lines[-1], flush=True)
     return lines
 

@@ -16,7 +16,9 @@ Sections:
   * roofline — aggregated dry-run table, if experiments/dryrun exists
 
 ``python -m benchmarks.run --quick`` runs only the fast sections (used by
-CI); the full run takes ~10 minutes on this container.
+CI); the full run takes ~10 minutes on a CPU host.  A section that raises
+prints its traceback and a ``nan`` line, the remaining sections still run,
+and the run then exits non-zero naming the failed sections.
 """
 
 from __future__ import annotations
@@ -27,6 +29,9 @@ import time
 import traceback
 
 
+_ERROR = ",nan,error="  # marks the summary line of a section that raised
+
+
 def _section(name: str, fn, lines: list[str]) -> None:
     print(f"# --- {name} ---", flush=True)
     try:
@@ -35,7 +40,16 @@ def _section(name: str, fn, lines: list[str]) -> None:
             lines.extend(out)
     except Exception as e:  # noqa: BLE001
         traceback.print_exc()
-        lines.append(f"{name},nan,error={type(e).__name__}")
+        lines.append(f"{name}{_ERROR}{type(e).__name__}")
+
+
+def _finish(lines: list[str]) -> None:
+    print("# --- summary CSV ---")
+    for line in lines:
+        print(line)
+    failed = [line.split(_ERROR)[0] for line in lines if _ERROR in line]
+    if failed:
+        sys.exit(f"benchmark sections failed: {', '.join(failed)}")
 
 
 def _anakin_single_device() -> list[str]:
@@ -227,9 +241,7 @@ def main() -> None:
     }
     if args.suite in suites:
         suites[args.suite](lines)
-        print("# --- summary CSV ---")
-        for line in lines:
-            print(line)
+        _finish(lines)
         return
 
     from benchmarks import kernel_bench
@@ -259,20 +271,15 @@ def main() -> None:
         _serve_suite(lines)
 
     # roofline table from dry-run artifacts, if present
-    try:
-        import glob
+    import glob
 
-        if glob.glob("experiments/dryrun/*.json"):
-            from benchmarks import roofline_table
+    if glob.glob("experiments/dryrun/*.json"):
+        from benchmarks import roofline_table
 
-            print("# --- roofline (from dry-run artifacts) ---")
-            roofline_table.main()
-    except Exception:  # noqa: BLE001
-        traceback.print_exc()
+        _section("roofline (from dry-run artifacts)",
+                 lambda: roofline_table.main() and None, lines)
 
-    print("# --- summary CSV ---")
-    for line in lines:
-        print(line)
+    _finish(lines)
 
 
 if __name__ == "__main__":

@@ -51,6 +51,9 @@ def measure(batch: int, frames: int = 20_000) -> float:
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    # a CPU study on placeholder devices by design: the child never
+    # reaches for an accelerator this process may hold
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", _SCRIPT.format(batch=batch, frames=frames,
                                               src=src)],
@@ -68,7 +71,8 @@ def main(batches=(12, 24, 48)) -> list[str]:
     lines = []
     for b in batches:
         fps = measure(b)
-        lines.append(f"sebulba_actor_batch_{b},{1e6 / fps:.3f},fps={fps:,.0f}")
+        lines.append(f"sebulba_actor_batch_{b},{1e6 / fps:.3f},"
+                     f"platform=cpu fps={fps:,.0f}")
         print(lines[-1], flush=True)
     return lines
 

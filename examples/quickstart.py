@@ -15,6 +15,7 @@ import jax
 
 from repro import optim
 from repro.agents.actor_critic import MLPActorCritic
+from repro.compile_cache import enable_compile_cache
 from repro.core.anakin import Anakin, AnakinConfig
 from repro.envs import Catch
 
@@ -30,6 +31,7 @@ def main() -> None:
                     help="checkpoint every N learner updates (0 = only the "
                          "final save when --checkpoint-dir is set)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     env = Catch()
     net = MLPActorCritic(env.num_actions, hidden=(64, 64))

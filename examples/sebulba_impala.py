@@ -13,6 +13,7 @@ import jax
 
 from repro import optim
 from repro.agents.impala import ConvActorCritic
+from repro.compile_cache import enable_compile_cache
 from repro.core.sebulba import Sebulba, SebulbaConfig
 from repro.envs import BatchedHostEnv, HostPong, Pong
 
@@ -45,6 +46,7 @@ def main() -> None:
                          "host_rejoin events hit the peers mid-run and the "
                          "learner reshards on each epoch bump)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     actor_cores = min(args.actor_cores, max(1, n_dev - 1)) if n_dev > 1 else 1

@@ -23,6 +23,7 @@ import jax
 
 from repro import optim
 from repro.agents.muzero import MuZeroAgent, MuZeroConfig
+from repro.compile_cache import enable_compile_cache
 from repro.core.sebulba import Sebulba, SebulbaConfig
 from repro.envs import BatchedHostEnv, HostPong
 
@@ -39,6 +40,7 @@ def main() -> None:
     ap.add_argument("--checkpoint-every", type=int, default=0,
                     help="checkpoint every N learner updates")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     actor_cores = min(2, n_dev - 1) if n_dev > 1 else 1

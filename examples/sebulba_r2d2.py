@@ -49,6 +49,7 @@ from repro.agents.recurrent import (
     RecurrentConvActorCritic,
     RecurrentReplayImpalaAgent,
 )
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ReplayConfig
 from repro.core.sebulba import Sebulba, SebulbaConfig
 from repro.envs import BatchedHostEnv, HostPong
@@ -89,6 +90,7 @@ def main() -> None:
     ap.add_argument("--checkpoint-every", type=int, default=0,
                     help="checkpoint every N learner updates")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     actor_cores = min(args.actor_cores, max(1, n_dev - 1)) if n_dev > 1 else 1

@@ -19,6 +19,7 @@ import jax
 from repro import optim
 from repro.agents.impala import ConvActorCritic
 from repro.api import ScenarioMix
+from repro.compile_cache import enable_compile_cache
 from repro.core.sebulba import Sebulba, SebulbaConfig
 from repro.envs import Pong
 
@@ -34,6 +35,7 @@ def main() -> None:
                          "stragglers across the device-env actor fleet) to "
                          "exercise supervision under the scenario mix")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     actor_cores = min(args.actor_cores, max(1, n_dev - 1)) if n_dev > 1 else 1

@@ -17,6 +17,7 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import get_reduced_config
 from repro.launch.steps import make_serve_step
 from repro.models import make_model
@@ -61,6 +62,7 @@ def main() -> None:
     ap.add_argument("--gen", type=int, default=64)
     ap.add_argument("--temperature", type=float, default=0.0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced_config(args.arch)
     model = make_model(cfg)

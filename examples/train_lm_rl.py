@@ -26,6 +26,7 @@ import jax
 from repro import optim
 from repro.agents.lm_policy import LMPolicyAgent, LMReplayPolicyAgent
 from repro.checkpoint import save
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ReplayConfig, get_config
 from repro.core.sebulba import Sebulba, SebulbaConfig
 from repro.envs import TokenEnv
@@ -58,8 +59,10 @@ def main() -> None:
     ap.add_argument("--replay", action="store_true",
                     help="train off-policy with prioritized replay "
                          "(the declared replay capability)")
-    ap.add_argument("--ckpt", default="experiments/train_lm_rl.npz")
+    ap.add_argument("--ckpt", default="",
+                    help="save the trained params here (default: no save)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     n_dev = len(jax.devices())
     actor_cores = min(args.actor_cores, max(1, n_dev - 1)) if n_dev > 1 else 1
@@ -113,8 +116,9 @@ def main() -> None:
     )
     if args.replay:
         print(f"replay: {out['replay_size']} trajectories held")
-    save(args.ckpt, out["params"])
-    print(f"checkpoint -> {args.ckpt}")
+    if args.ckpt:
+        save(args.ckpt, out["params"])
+        print(f"checkpoint -> {args.ckpt}")
 
 
 if __name__ == "__main__":

@@ -40,7 +40,6 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import numpy as np
 
 from repro import api, optim
-from repro.compat import shard_map
 from repro.envs.device_env import DeviceEnvFleet
 from repro.rl import losses
 
@@ -298,7 +297,7 @@ class Anakin:
 
             @functools.partial(jax.jit, donate_argnums=0)
             def run(state):
-                fn = shard_map(
+                fn = jax.shard_map(
                     lambda s: iterated(s, sync),
                     mesh=self.mesh,
                     in_specs=(AnakinState(
@@ -312,6 +311,7 @@ class Anakin:
                         ),
                         P(),
                     ),
+                    check_vma=False,
                 )
                 return fn(state)
 

@@ -107,7 +107,6 @@ from repro import api, optim
 # ImpalaAgent moved to repro/agents/impala.py with the repro.api redesign;
 # re-exported here for back-compat with pre-protocol imports.
 from repro.agents.impala import ImpalaAgent  # noqa: F401
-from repro.compat import shard_map
 from repro.configs.base import ReplayConfig
 from repro.core.supervision import (
     ActorHandle,
@@ -823,11 +822,12 @@ class Sebulba:
             return params, opt_state, metrics
 
         traj_spec = jax.tree.map(lambda _: P("batch"), example)
-        return shard_map(
+        return jax.shard_map(
             shard_update,
             mesh=self.learner_mesh,
             in_specs=(P(), P(), traj_spec),
             out_specs=(P(), P(), P()),
+            check_vma=False,
         )
 
     @staticmethod
@@ -971,11 +971,12 @@ class Sebulba:
 
         rspec = self._replay.state_spec(example)
         tspec = self._replay.batch_spec(example)
-        core = shard_map(
+        core = jax.shard_map(
             shard_update,
             mesh=self.learner_mesh,
             in_specs=(P(), P(), rspec, tspec, P(), P()),
             out_specs=(P(), P(), rspec, P()),
+            check_vma=False,
         )
 
         def update(params, opt_state, rstate, traj, macc, key, update_idx):

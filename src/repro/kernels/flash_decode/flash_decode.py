@@ -1,14 +1,16 @@
 """Flash-decoding for TPU in Pallas: single-token attention over a long KV
 cache (the Sebulba-actor / serve_step hot loop).
 
-Grid: (B, K, num_s_blocks) — the cache-sequence dimension is the sequential
-TPU grid axis; the online-softmax state for the G grouped query heads lives
-in VMEM scratch and persists across cache blocks.  Each grid step streams
-one (block_s, h) tile of K and V through the MXU against the (G, h) query
-tile, so the kernel is purely HBM-bandwidth-bound — the roofline floor for
-decode.  Blocks whose positions are entirely masked (beyond ``pos`` or
-outside the sliding window) are skipped with pl.when, so decode cost tracks
-the *filled* cache length, not the allocated one.
+Grid: (B, num_s_blocks) — the cache-sequence dimension is the sequential
+TPU grid axis; the online-softmax state for the K x G query heads lives in
+VMEM scratch and persists across cache blocks.  Each grid step streams one
+(block_s, K, h) tile of K and V — all kv heads, so the block's last two
+dims equal the cache's (K, h) as Mosaic requires — and runs each kv head's
+(block_s, h) slice through the MXU against its (G, h) query group, so the
+kernel is purely HBM-bandwidth-bound — the roofline floor for decode.
+Blocks whose positions are entirely masked (beyond ``pos`` or outside the
+sliding window) are skipped with pl.when, so decode cost tracks the
+*filled* cache length, not the allocated one.
 
 Decode positions are **per row**: the scalar-prefetch ``pos`` vector holds
 one int32 position per batch row (a scalar broadcasts), so rows of one
@@ -43,7 +45,7 @@ def _decode_kernel(
     window: int,
     sm_scale: float,
 ):
-    si = pl.program_id(2)
+    si = pl.program_id(1)
     pos = pos_ref[pl.program_id(0)]
 
     @pl.when(si == 0)
@@ -59,27 +61,35 @@ def _decode_kernel(
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32) * sm_scale  # (G, h)
-        k = k_ref[0, :, 0].astype(jnp.float32)  # (bs, h)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        s = q @ k.T  # (G, bs)
-        k_pos = s_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        k_pos = s_start + jax.lax.broadcasted_iota(
+            jnp.int32, (q_ref.shape[2], block_s), 1
+        )
         valid = k_pos <= pos
         if window:
             valid &= k_pos > pos - window
-        s = jnp.where(valid, s, NEG_INF)
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
-        p = jnp.exp(s - m_new[:, None])
-        scale = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * scale + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * scale[:, None] + p @ v
-        m_ref[...] = m_new
+        for kv in range(k_ref.shape[2]):  # static loop over the kv heads
+            q = q_ref[0, kv].astype(jnp.float32) * sm_scale  # (G, h)
+            k = k_ref[0, :, kv].astype(jnp.float32)  # (bs, h)
+            v = v_ref[0, :, kv].astype(jnp.float32)
+            s = jax.lax.dot_general(
+                q, k, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32,
+            )  # (G, bs)
+            s = jnp.where(valid, s, NEG_INF)
+            m_prev = m_ref[kv]  # (G, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            p = jnp.exp(s - m_new)
+            scale = jnp.exp(m_prev - m_new)
+            l_ref[kv] = l_ref[kv] * scale + p.sum(axis=1, keepdims=True)
+            acc_ref[kv] = acc_ref[kv] * scale + jnp.dot(
+                p, v, preferred_element_type=jnp.float32
+            )
+            m_ref[kv] = m_new
 
     @pl.when(si == num_s_blocks - 1)
     def _finalize():
         l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
+        o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _paged_decode_kernel(pos_ref, bt_ref, *rest, **kw):
@@ -87,6 +97,17 @@ def _paged_decode_kernel(pos_ref, bt_ref, *rest, **kw):
     # kernel body masks on logical positions exactly like the dense one
     del bt_ref
     _decode_kernel(pos_ref, *rest, **kw)
+
+
+def _scratch(K: int, G: int, h: int) -> list:
+    """Online-softmax state per kv head: running max, denominator, and
+    output accumulator.  The max and denominator are (G, 1) columns: TPU
+    scratch is at least 2-D."""
+    return [
+        pltpu.VMEM((K, G, 1), jnp.float32),
+        pltpu.VMEM((K, G, 1), jnp.float32),
+        pltpu.VMEM((K, G, h), jnp.float32),
+    ]
 
 
 def _pos_vector(pos, batch: int) -> jax.Array:
@@ -119,7 +140,10 @@ def flash_decode_pallas(
     ns = S // block_s
 
     qh = q.reshape(B, K, G, h)  # (B, K, G, h)
-    grid = (B, K, ns)
+    q_spec = pl.BlockSpec((1, K, G, h), lambda b, si, pos: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (1, block_s, K, h), lambda b, si, pos: (b, si, 0, 0)
+    )
     out = pl.pallas_call(
         functools.partial(
             _decode_kernel,
@@ -128,24 +152,10 @@ def flash_decode_pallas(
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, G, h), lambda b, k, si, pos: (b, k, 0, 0)),
-                pl.BlockSpec(
-                    (1, block_s, 1, h), lambda b, k, si, pos: (b, si, k, 0)
-                ),
-                pl.BlockSpec(
-                    (1, block_s, 1, h), lambda b, k, si, pos: (b, si, k, 0)
-                ),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, G, h), lambda b, k, si, pos: (b, k, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G, h), jnp.float32),
-            ],
+            grid=(B, ns),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=_scratch(K, G, h),
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, h), q.dtype),
         interpret=interpret,
@@ -177,7 +187,10 @@ def flash_decode_pallas_paged(
     G = H // K
 
     qh = q.reshape(B, K, G, h)
-    grid = (B, K, nb)
+    q_spec = pl.BlockSpec((1, K, G, h), lambda b, si, pos, bt: (b, 0, 0, 0))
+    kv_spec = pl.BlockSpec(
+        (1, bs, K, h), lambda b, si, pos, bt: (bt[b, si], 0, 0, 0)
+    )
     out = pl.pallas_call(
         functools.partial(
             _paged_decode_kernel,
@@ -185,28 +198,10 @@ def flash_decode_pallas_paged(
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,  # pos, block_tables
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec(
-                    (1, 1, G, h), lambda b, k, si, pos, bt: (b, k, 0, 0)
-                ),
-                pl.BlockSpec(
-                    (1, bs, 1, h),
-                    lambda b, k, si, pos, bt: (bt[b, si], 0, k, 0),
-                ),
-                pl.BlockSpec(
-                    (1, bs, 1, h),
-                    lambda b, k, si, pos, bt: (bt[b, si], 0, k, 0),
-                ),
-            ],
-            out_specs=pl.BlockSpec(
-                (1, 1, G, h), lambda b, k, si, pos, bt: (b, k, 0, 0)
-            ),
-            scratch_shapes=[
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G,), jnp.float32),
-                pltpu.VMEM((G, h), jnp.float32),
-            ],
+            grid=(B, nb),
+            in_specs=[q_spec, kv_spec, kv_spec],
+            out_specs=q_spec,
+            scratch_shapes=_scratch(K, G, h),
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, h), q.dtype),
         interpret=interpret,

@@ -3,10 +3,10 @@
 Grid: (B, num_width_blocks, num_time_blocks) — time is the sequential TPU
 grid dimension; the hidden state (one row of width ``block_w``) is carried
 in VMEM scratch across time blocks.  Within a time block the recurrence
-runs as an unrolled-by-lax.fori_loop elementwise loop over rows that are
-already resident in VMEM — the same structure as the custom linear-scan
-kernel the Griffin paper used on TPU (sequential in time, fully parallel in
-batch x width on the VPU lanes).
+runs as a lax.fori_loop over the rows of f32 VMEM scratch (Mosaic
+addresses single rows of 32-bit data only) — the same structure as the
+custom linear-scan kernel the Griffin paper used on TPU (sequential in
+time, fully parallel in batch x width on the VPU lanes).
 """
 
 from __future__ import annotations
@@ -19,31 +19,32 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _rglru_kernel(x_ref, a_ref, i_ref, y_ref, h_ref, *, block_t: int):
+def _rglru_kernel(x_ref, a_ref, i_ref, y_ref, h_ref, a32_ref, y32_ref):
     ti = pl.program_id(2)
 
     @pl.when(ti == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    x = x_ref[0].astype(jnp.float32)  # (bt, bw)
-    a = a_ref[0].astype(jnp.float32)
-    gi = i_ref[0].astype(jnp.float32)
+    # stage the block in f32 scratch: u = beta * (i * x) in the output
+    # buffer, the decay gate beside it
+    a = a_ref[0].astype(jnp.float32)  # (bt, bw)
     beta = jnp.sqrt(jnp.maximum(1.0 - a * a, 0.0))
-    u = beta * (gi * x)  # (bt, bw)
+    a32_ref[...] = a
+    y32_ref[...] = beta * (i_ref[0].astype(jnp.float32) * x_ref[0].astype(
+        jnp.float32
+    ))
 
-    def step(t, carry):
-        h, ys = carry
-        h = a[t] * h + u[t]
-        ys = jax.lax.dynamic_update_index_in_dim(ys, h, t, 0)
-        return (h, ys)
+    def step(t, h):
+        # one (1, bw) f32 row per step, read and written through the refs:
+        # the time index is a sublane offset, never a slice of a value
+        row = pl.ds(t, 1)
+        h = a32_ref[row, :] * h + y32_ref[row, :]
+        y32_ref[row, :] = h
+        return h
 
-    h0 = h_ref[...]
-    h_final, ys = jax.lax.fori_loop(
-        0, block_t, step, (h0, jnp.zeros_like(u))
-    )
-    y_ref[0] = ys.astype(y_ref.dtype)
-    h_ref[...] = h_final
+    h_ref[...] = jax.lax.fori_loop(0, a.shape[0], step, h_ref[...])
+    y_ref[0] = y32_ref[...].astype(y_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "block_w", "interpret"))
@@ -68,12 +69,16 @@ def rglru_scan_pallas(
 
     spec = pl.BlockSpec((1, bt, bw), lambda b, wi, ti: (b, ti, wi))
     y = pl.pallas_call(
-        functools.partial(_rglru_kernel, block_t=bt),
+        _rglru_kernel,
         grid=grid,
         in_specs=[spec, spec, spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct((B, T, W), x.dtype),
-        scratch_shapes=[pltpu.VMEM((bw,), jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((1, bw), jnp.float32),  # h, carried across blocks
+            pltpu.VMEM((bt, bw), jnp.float32),
+            pltpu.VMEM((bt, bw), jnp.float32),
+        ],
         interpret=interpret,
     )(x, a, gate_i)
     return y, y[:, -1].astype(jnp.float32)

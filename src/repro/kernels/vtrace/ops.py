@@ -1,13 +1,16 @@
 """Jitted V-trace wrapper with Pallas/TPU dispatch.
 
 V-trace is the RL hot loop that every Sebulba learner step runs over the
-full (B, T) trajectory batch.  On TPU it runs as a Pallas kernel (batch
-rows tiled into VMEM, the T-recursion sequential in-register); elsewhere the
-jnp reference runs (identical math).  ``interpret=True`` exercises the
+full (B, T) trajectory batch.  On TPU it runs as a Pallas kernel
+(time-major (T, block_b) columns in VMEM, the T-recursion sequential over
+rows); elsewhere the jnp reference runs (identical math).  ``interpret=True`` exercises the
 Pallas kernel on CPU for tests.
 
 No gradients flow through v-trace targets (IMPALA treats vs / advantages as
-constants), so the op is wrapped in stop_gradient and needs no custom VJP.
+constants), so the op needs no custom VJP.  The gradients stop at the
+op's *inputs*: stopping them only at its outputs would still make
+``jax.grad`` linearize the ``pallas_call`` (its inputs ``log_rhos`` and
+``values`` carry tangents in every learner loss), which fails.
 """
 
 from __future__ import annotations
@@ -37,17 +40,19 @@ def vtrace(
 ) -> VTraceOutput:
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    log_rhos, discounts, rewards, values, bootstrap_value = (
+        jax.lax.stop_gradient(x)
+        for x in (log_rhos, discounts, rewards, values, bootstrap_value)
+    )
     if impl == "pallas" or interpret:
         from repro.kernels.vtrace.vtrace import vtrace_pallas
 
-        out = vtrace_pallas(
+        return vtrace_pallas(
             log_rhos, discounts, rewards, values, bootstrap_value,
             clip_rho=clip_rho, clip_c=clip_c, lambda_=lambda_,
             interpret=interpret,
         )
-    else:
-        out = vtrace_ref(
-            log_rhos, discounts, rewards, values, bootstrap_value,
-            clip_rho=clip_rho, clip_c=clip_c, lambda_=lambda_,
-        )
-    return VTraceOutput(*jax.tree.map(jax.lax.stop_gradient, tuple(out)))
+    return vtrace_ref(
+        log_rhos, discounts, rewards, values, bootstrap_value,
+        clip_rho=clip_rho, clip_c=clip_c, lambda_=lambda_,
+    )

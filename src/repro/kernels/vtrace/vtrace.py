@@ -1,10 +1,12 @@
 """V-trace reverse recursion as a Pallas TPU kernel.
 
-Grid: (num_batch_blocks,).  A block of trajectory rows (block_b, T) is
-resident in VMEM; the reverse time recursion runs as a fori_loop with the
-accumulator held in registers/VMEM, fully parallel across the batch rows in
-the VPU lanes.  One kernel launch computes both vs and pg_advantages —
-fusing what would otherwise be two XLA while-loops over T.
+Grid: (num_batch_blocks,).  The kernel works time-major: a block of
+trajectory columns (T, block_b) is resident in VMEM with the batch on the
+lanes, and the reverse time recursion runs as a fori_loop that reads and
+writes one (1, block_b) row of the refs per step — the time index is a
+sublane offset into a ref, never a lane slice of a value, which Mosaic
+cannot lower.  One pass computes both vs and pg_advantages — fusing what
+would otherwise be two XLA while-loops over T.
 """
 
 from __future__ import annotations
@@ -17,7 +19,7 @@ from jax.experimental import pallas as pl
 
 
 def _vtrace_kernel(
-    logr_ref, disc_ref, rew_ref, val_ref, boot_ref,
+    logr_ref, disc_ref, rew_ref, val_ref, boot_ref,  # (T, bb); boot (1, bb)
     vs_ref, adv_ref,
     *,
     clip_rho: float,
@@ -25,32 +27,23 @@ def _vtrace_kernel(
     lambda_: float,
     T: int,
 ):
-    rhos = jnp.exp(logr_ref[...].astype(jnp.float32))  # (bb, T)
-    clipped = jnp.minimum(clip_rho, rhos)
-    cs = lambda_ * jnp.minimum(clip_c, rhos)
-    disc = disc_ref[...].astype(jnp.float32)
-    rew = rew_ref[...].astype(jnp.float32)
-    val = val_ref[...].astype(jnp.float32)
-    boot = boot_ref[...].astype(jnp.float32)  # (bb,)
-
-    v_tp1 = jnp.concatenate([val[:, 1:], boot[:, None]], axis=1)
-    deltas = clipped * (rew + disc * v_tp1 - val)
-
     def step(i, carry):
-        acc, errs = carry  # acc (bb,), errs (bb, T)
-        t = T - 1 - i
-        acc = deltas[:, t] + disc[:, t] * cs[:, t] * acc
-        errs = jax.lax.dynamic_update_index_in_dim(errs, acc, t, 1)
-        return (acc, errs)
+        # carry: (vs_{t+1} - V_{t+1}, V_{t+1}, vs_{t+1}), each (1, bb)
+        acc, v_tp1, vs_tp1 = carry
+        t = pl.ds(T - 1 - i, 1)
+        rho = jnp.exp(logr_ref[t, :])
+        clipped = jnp.minimum(clip_rho, rho)
+        c = lambda_ * jnp.minimum(clip_c, rho)
+        disc, rew, val = disc_ref[t, :], rew_ref[t, :], val_ref[t, :]
+        delta = clipped * (rew + disc * v_tp1 - val)
+        acc = delta + disc * c * acc
+        vs = val + acc
+        vs_ref[t, :] = vs
+        adv_ref[t, :] = clipped * (rew + disc * vs_tp1 - val)
+        return acc, val, vs
 
-    _, errs = jax.lax.fori_loop(
-        0, T, step, (jnp.zeros_like(boot), jnp.zeros_like(val))
-    )
-    vs = val + errs
-    vs_tp1 = jnp.concatenate([vs[:, 1:], boot[:, None]], axis=1)
-    adv = clipped * (rew + disc * vs_tp1 - val)
-    vs_ref[...] = vs
-    adv_ref[...] = adv
+    boot = boot_ref[...]
+    jax.lax.fori_loop(0, T, step, (jnp.zeros_like(boot), boot, boot))
 
 
 @functools.partial(
@@ -79,33 +72,24 @@ def vtrace_pallas(
     # most and compute benign values (log_rho 0 -> rho 1, everything else
     # 0), which are sliced off before returning
     B_pad = -(-B // bb) * bb
-    if B_pad != B:
-        row_pad = lambda x: jnp.pad(
-            x, ((0, B_pad - B),) + ((0, 0),) * (x.ndim - 1)
-        )
-        log_rhos, discounts, rewards, values, bootstrap_value = (
-            row_pad(log_rhos), row_pad(discounts), row_pad(rewards),
-            row_pad(values), row_pad(bootstrap_value),
-        )
-    grid = (B_pad // bb,)
-    spec2 = pl.BlockSpec((bb, T), lambda i: (i, 0))
-    spec1 = pl.BlockSpec((bb,), lambda i: (i,))
-    to_f32 = lambda x: x.astype(jnp.float32)
+    # time-major f32 columns (T, B_pad); the batch rides the lanes
+    cols = lambda x: jnp.pad(
+        x.astype(jnp.float32).reshape(B, -1), ((0, B_pad - B), (0, 0))
+    ).T
+    spec = pl.BlockSpec((T, bb), lambda i: (0, i))
+    spec_boot = pl.BlockSpec((1, bb), lambda i: (0, i))
     vs, adv = pl.pallas_call(
         functools.partial(
             _vtrace_kernel, clip_rho=clip_rho, clip_c=clip_c,
             lambda_=lambda_, T=T,
         ),
-        grid=grid,
-        in_specs=[spec2, spec2, spec2, spec2, spec1],
-        out_specs=[spec2, spec2],
-        out_shape=[
-            jax.ShapeDtypeStruct((B_pad, T), jnp.float32),
-            jax.ShapeDtypeStruct((B_pad, T), jnp.float32),
-        ],
+        grid=(B_pad // bb,),
+        in_specs=[spec, spec, spec, spec, spec_boot],
+        out_specs=[spec, spec],
+        out_shape=[jax.ShapeDtypeStruct((T, B_pad), jnp.float32)] * 2,
         interpret=interpret,
     )(
-        to_f32(log_rhos), to_f32(discounts), to_f32(rewards), to_f32(values),
-        to_f32(bootstrap_value),
+        cols(log_rhos), cols(discounts), cols(rewards), cols(values),
+        cols(bootstrap_value),
     )
-    return VTraceOutput(vs=vs[:B], pg_advantages=adv[:B])
+    return VTraceOutput(vs=vs.T[:B], pg_advantages=adv.T[:B])

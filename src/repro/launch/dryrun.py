@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import optim
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import (
     ALIASES,
     ARCH_IDS,
@@ -277,6 +278,7 @@ def main() -> None:
     ap.add_argument("--optimized", action="store_true",
                     help="apply the §Perf-tuned rule set")
     args = ap.parse_args()
+    enable_compile_cache()
 
     def parse_val(v):
         if v.lower() in ("none", "null"):

@@ -8,6 +8,7 @@ before the first jax device query.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -20,9 +21,11 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(axis: str = "batch"):
     """All local devices on one axis (Anakin replication / tests)."""
-    return jax.make_mesh((len(jax.devices()),), (axis,))
+    return jax.make_mesh(
+        (len(jax.devices()),), (axis,), axis_types=(AxisType.Auto,)
+    )

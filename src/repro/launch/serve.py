@@ -4,6 +4,10 @@ ssm/hybrid recurrent state has no paged layout).
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --gen 32
     PYTHONPATH=src python -m repro.launch.serve --arch mamba2-1.3b --gen 32
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen2-1.5b --full
+
+The path is chosen from the model before anything runs
+(``engine_refusal``); an engine failure is an error, never a fallback.
 """
 
 from __future__ import annotations
@@ -14,10 +18,12 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro.configs.base import ALIASES, get_reduced_config
+from repro.compile_cache import enable_compile_cache
+from repro.configs.base import ALIASES, get_config, get_reduced_config
 from repro.launch.steps import make_serve_step
 from repro.models import make_model
 from repro.serve import Request, ServeConfig, ServeEngine
+from repro.serve.engine import engine_refusal
 
 
 def _serve_static(model, params, args) -> None:
@@ -73,6 +79,8 @@ def _serve_engine(model, params, args) -> None:
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, help=f"one of {sorted(ALIASES)}")
+    ap.add_argument("--full", action="store_true",
+                    help="published widths and depth (needs an accelerator)")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--gen", type=int, default=32)
     ap.add_argument("--cache-len", type=int, default=128)
@@ -80,15 +88,15 @@ def main() -> None:
     ap.add_argument("--top-k", type=int, default=0)
     args = ap.parse_args()
 
-    cfg = get_reduced_config(args.arch)
+    enable_compile_cache()
+    cfg = get_config(args.arch) if args.full else get_reduced_config(args.arch)
     model = make_model(cfg)
     params = model.init(jax.random.key(0))
-    try:
+    reason = engine_refusal(model)
+    if reason is None:
         _serve_engine(model, params, args)
-    except ValueError as e:
-        # family the engine can't page (recurrent state, local attention,
-        # softcap) — serve it with the static lockstep loop instead
-        print(f"[serve] falling back to static batching: {e}")
+    else:
+        print(f"[serve] static batching: {reason}")
         _serve_static(model, params, args)
 
 

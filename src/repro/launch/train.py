@@ -16,6 +16,7 @@ import jax
 
 from repro import optim
 from repro.checkpoint import save
+from repro.compile_cache import enable_compile_cache
 from repro.configs.base import ALIASES, get_config, get_reduced_config
 from repro.launch.specs import make_batch
 from repro.launch.steps import TrainHParams, make_train_step
@@ -34,6 +35,7 @@ def main() -> None:
     ap.add_argument("--moe-impl", default="sort")
     ap.add_argument("--ckpt", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch) if args.full else get_reduced_config(args.arch)
     mesh = None

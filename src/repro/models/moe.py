@@ -30,7 +30,6 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import shard_map
 from repro.models import layers
 from repro.param import ParamBuilder, fan_in_init, normal_init
 
@@ -233,7 +232,7 @@ def moe_ffn_a2a(
         return y, aux, zloss
 
     first = data_axes if data_axes else None
-    fn = shard_map(
+    fn = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(
@@ -242,6 +241,7 @@ def moe_ffn_a2a(
             P(model_axis), P(model_axis), P(model_axis),  # expert shards
         ),
         out_specs=(P(first, None), P(), P()),
+        check_vma=False,
     )
     y, aux, zloss = fn(
         x_flat, params["router"], params["w_gate"], params["w_up"],

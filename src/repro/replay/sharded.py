@@ -25,7 +25,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.replay import buffer
 from repro.replay.buffer import ReplayState
 
@@ -129,9 +128,10 @@ class ShardedReplay:
             )
 
         self._insert_fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 _insert, mesh=self.mesh, in_specs=(spec, bspec),
                 out_specs=spec,
+                check_vma=False,
             ),
             donate_argnums=0,
         )
@@ -140,10 +140,11 @@ class ShardedReplay:
             return buffer.update_priorities(st, idx, new_p)
 
         self._update_fn = jax.jit(
-            shard_map(
+            jax.shard_map(
                 _update, mesh=self.mesh,
                 in_specs=(spec, P(self.axis), P(self.axis)),
                 out_specs=spec,
+                check_vma=False,
             ),
             donate_argnums=0,
         )
@@ -189,10 +190,11 @@ class ShardedReplay:
                 )
 
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     _sample, mesh=self.mesh,
                     in_specs=(self._spec, P()),
                     out_specs=(self._bspec, P(self.axis), P(self.axis)),
+                    check_vma=False,
                 )
             )
             self._sample_fns[batch_size] = fn

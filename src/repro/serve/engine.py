@@ -43,6 +43,20 @@ from repro.serve.scheduler import Request, Scheduler, ServeConfig
 PyTree = Any
 
 
+def engine_refusal(model) -> str | None:
+    """Why ``ServeEngine`` cannot serve ``model``, or None when it can.
+    Callers choose their serving path from this before they build
+    anything."""
+    cfg = model.cfg
+    if cfg.family not in ("dense", "moe"):
+        return f"ServeEngine serves dense/moe models, not {cfg.family}"
+    if cfg.attn_logit_softcap:
+        return "ServeEngine does not support logit softcap"
+    if any(tf.local_params(cfg, kind)[0] for kind in model.kinds):
+        return "ServeEngine requires uniform global attention"
+    return None
+
+
 class ServeEngine:
     """Continuous-batching engine over a dense or paged KV cache.
 
@@ -54,17 +68,9 @@ class ServeEngine:
 
     def __init__(self, model, params: PyTree, cfg: ServeConfig,
                  paged: bool = True):
-        if model.cfg.family not in ("dense", "moe"):
-            raise ValueError(
-                f"ServeEngine serves dense/moe models, not {model.cfg.family}"
-            )
-        if model.cfg.attn_logit_softcap:
-            raise ValueError("ServeEngine does not support logit softcap")
-        for kind in model.kinds:
-            if tf.local_params(model.cfg, kind)[0]:
-                raise ValueError(
-                    "ServeEngine requires uniform global attention"
-                )
+        reason = engine_refusal(model)
+        if reason:
+            raise ValueError(reason)
         cfg.validate()
         self.model = model
         self.params = params

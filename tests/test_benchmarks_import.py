@@ -76,3 +76,19 @@ def test_run_registers_serve_suite():
 
     assert '"serve": _serve_suite' in inspect.getsource(run.main)
     assert "BENCH_serve.json" in inspect.getsource(run._serve_suite)
+
+
+def test_run_fails_after_a_section_raises(capsys):
+    """A section that raises does not stop the others, and the run then
+    exits non-zero naming it: no failure is turned into success."""
+    from benchmarks import run
+
+    lines: list[str] = []
+    run._section("fine", lambda: ["fine,1.0,x"], lines)
+    run._section("broken", lambda: 1 / 0, lines)
+    run._section("after", lambda: ["after,2.0,y"], lines)
+    with pytest.raises(SystemExit) as exit_info:
+        run._finish(lines)
+    assert "broken" in str(exit_info.value.code)
+    assert "fine" not in str(exit_info.value.code)
+    assert "after,2.0,y" in capsys.readouterr().out

@@ -78,8 +78,11 @@ def test_example_module_imports(name):
 
 @pytest.mark.slow
 @pytest.mark.parametrize("label", sorted(_RL_RUNS))
-def test_rl_example_runs_end_to_end(label):
+def test_rl_example_runs_end_to_end(label, tmp_path):
     name, argv = _RL_RUNS[label]
+    if name == "train_lm_rl":
+        # the checkpoint goes to the test's own directory, never the repo
+        argv = [*argv, "--ckpt", str(tmp_path / "train_lm_rl.npz")]
     src = str(pathlib.Path(__file__).resolve().parent.parent / "src")
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=2"
@@ -92,6 +95,8 @@ def test_rl_example_runs_end_to_end(label):
     )
     assert proc.returncode == 0, proc.stderr[-3000:]
     assert "FPS" in proc.stdout, proc.stdout[-2000:]
+    if name == "train_lm_rl":
+        assert (tmp_path / "train_lm_rl.npz").exists()
     if "--chaos" in argv:
         # the chaos run must survive its schedule and report supervision
         # counters (the example prints them only when --chaos is set)

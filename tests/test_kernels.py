@@ -159,3 +159,42 @@ def test_vtrace_matches_ref(B, T, bb):
     o_p = vtrace_pallas(lr, disc, rew, val, boot, block_b=bb, interpret=True)
     assert jnp.abs(o_p.vs - o_ref.vs).max() < 1e-5
     assert jnp.abs(o_p.pg_advantages - o_ref.pg_advantages).max() < 1e-5
+
+
+def test_impala_loss_grad_through_pallas_vtrace_matches_jnp(monkeypatch):
+    """``jax.grad`` of the IMPALA loss with the Pallas V-trace (interpret
+    mode) gives the jnp path's values and gradients: the kernel's inputs
+    stop gradients, so autodiff never linearizes the ``pallas_call``."""
+    import functools
+
+    from repro.kernels.vtrace import ops
+    from repro.rl import losses
+
+    B, T, A = 6, 9, 4
+    ks = jax.random.split(jax.random.key(5), 6)
+    logits = jax.random.normal(ks[0], (B, T, A))
+    values = jax.random.normal(ks[1], (B, T))
+    actions = jax.random.randint(ks[2], (B, T), 0, A)
+    blogp = jnp.log(jax.random.uniform(ks[3], (B, T), minval=0.2, maxval=0.9))
+    rewards = jax.random.normal(ks[4], (B, T))
+    discounts = jnp.full((B, T), 0.99)
+    boot = jax.random.normal(ks[5], (B,))
+
+    def value_and_grad():
+        def total(logits, values):
+            return losses.weighted_impala_loss(
+                logits, values, actions, blogp, rewards, discounts, boot
+            ).total
+
+        return jax.value_and_grad(total, argnums=(0, 1))(logits, values)
+
+    monkeypatch.setattr(
+        losses, "vtrace", functools.partial(ops.vtrace, impl="jnp")
+    )
+    want = value_and_grad()
+    monkeypatch.setattr(
+        losses, "vtrace", functools.partial(ops.vtrace, interpret=True)
+    )
+    got = value_and_grad()
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert jnp.abs(g - w).max() < 1e-5

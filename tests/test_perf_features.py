@@ -88,7 +88,10 @@ _A2A_SCRIPT = textwrap.dedent(
     from repro.models import moe as moe_lib
     from repro.param import ParamBuilder
 
-    mesh = jax.make_mesh((2, 2), ("data", "model"))
+    mesh = jax.make_mesh(
+        (2, 2), ("data", "model"),
+        axis_types=(jax.sharding.AxisType.Auto,) * 2,
+    )
     dims = moe_lib.MoEDims(32, 16, 4, 2, 1, 8.0)
     b = ParamBuilder(jax.random.key(0))
     moe_lib.init_moe(b, "moe", dims)

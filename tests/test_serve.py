@@ -213,6 +213,27 @@ def test_engine_rejects_unpageable_families():
         ServeEngine(model, None, ServeConfig())
 
 
+@pytest.mark.parametrize(
+    "arch,served",
+    [("qwen2-1.5b", True), ("deepseek-moe-16b", True),
+     ("mamba2-1.3b", False), ("recurrentgemma-2b", False),
+     ("gemma3-4b", False)],
+)
+def test_engine_refusal_decides_the_serving_path(arch, served):
+    """The launcher picks engine or static loop from ``engine_refusal``
+    before it builds anything; the engine refuses exactly what it names."""
+    from repro.configs.base import get_reduced_config
+    from repro.models.model import make_model
+    from repro.serve.engine import engine_refusal
+
+    model = make_model(get_reduced_config(arch))
+    reason = engine_refusal(model)
+    assert (reason is None) == served, reason
+    if not served:
+        with pytest.raises(ValueError, match=reason):
+            ServeEngine(model, None, ServeConfig())
+
+
 @pytest.fixture(scope="module")
 def small_lm():
     from repro.configs.base import get_config
