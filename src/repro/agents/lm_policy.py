@@ -112,9 +112,10 @@ class LMPolicyAgent:
         logits, _, cache = self.model.decode_step(
             params, carry["cache"], tokens, pos
         )
-        logits = logits[:, 0].astype(jnp.float32)
-        actions = jax.random.categorical(rng, logits)
-        logp = losses.log_prob(logits, actions)
+        with jax.named_scope("sampling"):
+            logits = logits[:, 0].astype(jnp.float32)
+            actions = jax.random.categorical(rng, logits)
+            logp = losses.log_prob(logits, actions)
         return actions, ActAux(logp), {"cache": cache, "pos": carry["pos"] + 1}
 
     # -- learner ----------------------------------------------------------

@@ -41,6 +41,13 @@ keys):
     reshards           membership epoch bumps observed (each triggers the
                        deterministic replay reshard + forced republish)
     epoch              final membership epoch (0 when not multi-host)
+    policy_lag_mean    mean over updates of the params versions published
+                       between the one a trajectory's first step acted with
+                       and the one its update starts from (Sebulba, where
+                       with replay it is the lag of the trajectory each
+                       update inserts; 0 for Anakin, on-policy by
+                       construction)
+    policy_lag_max     the largest such lag
     mean_return        mean episode return (NaN when untracked)
     metrics            drained learner metrics (means since last drain)
     scenarios          per-scenario counters when training on a device-env
@@ -62,6 +69,7 @@ metric drains, it never touches the steady-state donated update loop.
 
 from __future__ import annotations
 
+import contextlib
 import math
 import os
 import re
@@ -96,6 +104,8 @@ RESULT_KEYS = (
     "hosts_lost",
     "reshards",
     "epoch",
+    "policy_lag_mean",
+    "policy_lag_max",
     "mean_return",
     "metrics",
     "scenarios",
@@ -117,6 +127,7 @@ _COUNTER_DEFAULTS = {
     "hosts_lost": 0,
     "reshards": 0,
     "epoch": 0,
+    "policy_lag_max": 0,
 }
 
 
@@ -149,6 +160,7 @@ def make_result(
     metrics: dict,
     mean_return: float = float("nan"),
     scenarios: dict | None = None,
+    policy_lag_mean: float = 0.0,
     **counters: int,
 ) -> dict:
     """Assemble the unified runner result.  Unset counters default to 0;
@@ -163,6 +175,7 @@ def make_result(
         "fps": float(frames) / seconds if seconds > 0 else 0.0,
         "seconds": float(seconds),
         "mean_return": float(mean_return),
+        "policy_lag_mean": float(policy_lag_mean),
         "metrics": dict(metrics),
         "scenarios": dict(scenarios) if scenarios else {},
     }
@@ -416,7 +429,8 @@ class CheckpointPolicy:
     checkpoint_dir=...)`` alone persists the result."""
 
     def __init__(self, directory: str | None, every: int,
-                 base_updates: int = 0, fault=None):
+                 base_updates: int = 0, fault=None,
+                 span: str | None = None):
         if every < 0:
             raise ValueError("checkpoint_every must be >= 0")
         if every and not directory:
@@ -427,6 +441,7 @@ class CheckpointPolicy:
         self.directory = directory
         self.every = every
         self.fault = fault  # checkpoint fault injector (repro.fault)
+        self.span = span  # profiler span around each write, or None
         self.saved = 0
         self._last_version = None
         # seed the boundary from the restored update count, so a resumed
@@ -437,10 +452,12 @@ class CheckpointPolicy:
 
     def _save(self, params, *, param_version: int, updates: int,
               frames: int) -> None:
-        save_checkpoint(
-            self.directory, params, param_version=param_version,
-            updates=updates, frames=frames, fault=self.fault,
-        )
+        with (jax.profiler.TraceAnnotation(self.span) if self.span
+              else contextlib.nullcontext()):
+            save_checkpoint(
+                self.directory, params, param_version=param_version,
+                updates=updates, frames=frames, fault=self.fault,
+            )
         self.saved += 1
         self._last_version = param_version
 

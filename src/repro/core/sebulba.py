@@ -86,6 +86,47 @@ are dropped at the learner (the epoch-checked insert path — a trajectory
 routed under a dead membership never crosses the bump), and the result
 schema reports ``hosts_joined`` / ``hosts_lost`` / ``reshards`` /
 ``epoch``.  See ARCHITECTURE.md §Multi-host elasticity.
+
+Tracing: the runner opens ``jax.profiler.TraceAnnotation`` spans at its
+queue, update and publish, always; while no profiler runs a span records
+nothing and costs only its Python call.  Capture them with the device's
+ops, on one clock, by running ``fit`` under ``jax.profiler.trace(log_dir)``
+(or between ``jax.profiler.start_trace`` and ``stop_trace``) and open the
+capture in TensorBoard's or Perfetto's trace viewer; a device idle gap
+then lines up with the span the host had open.  A span is recorded only if
+it opens and closes while the capture runs.  Every Python thread shows on
+a host line named after the interpreter, so the role is in the span's
+name:
+
+  sebulba.actor.put         an actor thread handing a trajectory to the
+                            learner queue, full-queue retries included;
+                            stats ``traj`` (the trajectory's id,
+                            ``<slot>r<incarnation>:<sequence>``) and
+                            ``version`` (the params version its first step
+                            acted with)
+  sebulba.learner.get       each wait on the queue (timeouts included)
+  sebulba.learner.update    the update's dispatch; stats ``traj``,
+                            ``version`` and ``lag`` (with replay, of the
+                            trajectory the update inserts; the batch it
+                            trains on is sampled from the buffer)
+  sebulba.learner.publish   the parameter publish; stats ``version``,
+                            ``sent`` and ``skipped`` (actor cores)
+  sebulba.learner.checkpoint  a checkpoint write
+  sebulba.learner.log       the ``log_every`` metrics drain and print
+
+One trajectory's put and update share its ``traj``.  The policy lag of an
+update is the number of params versions published between the one its
+trajectory's first step acted with and the one the update starts from;
+the result reports its mean and maximum (``policy_lag_mean``,
+``policy_lag_max``) and the ``log_every`` line prints them.  The queue's
+capacity, the actor threads and the publish throttle set it; V-trace
+corrects for it.  The programs keep stable names for the trace's
+reduction: the act step ``jit__device_act_step_fn`` (``jit__act_step_fn``
+with host envs), the update ``jit_update``; named scopes mark the act
+step's decode layers, unembedding, sampling, ring write and env step, and
+the update's ``forward_backward`` (inside it JAX's ``jvp(...)`` and
+``transpose(jvp(...))`` prefixes split the two passes), V-trace and
+optimizer.
 """
 
 from __future__ import annotations
@@ -448,19 +489,28 @@ class Sebulba:
         self._params_version += 1
         version = self._params_version
         throttle = self.cfg.publish_throttle and not force
-        for i, dev in enumerate(self.split.actor_devices):
-            if throttle and self._slot_consumed[i] < self._param_slots[i][0]:
-                self.publishes_skipped += 1
-                continue
-            fresh = jax.device_put(params, dev)
-            if dev in self._shared_devices:
-                # device_put to the device params already live on returns a
-                # handle on the SAME buffers — buffers the donated learner
-                # update is about to consume.  Give the slot private storage
-                # so actors never read donated-away memory.
-                fresh = jax.tree.map(jnp.copy, fresh)
-            self._param_slots[i] = (version, fresh)
-            self.publishes_sent += 1
+        sent = skipped = 0
+        with jax.profiler.TraceAnnotation(
+            "sebulba.learner.publish", version=version
+        ) as span:
+            for i, dev in enumerate(self.split.actor_devices):
+                if (throttle
+                        and self._slot_consumed[i] < self._param_slots[i][0]):
+                    skipped += 1
+                    continue
+                fresh = jax.device_put(params, dev)
+                if dev in self._shared_devices:
+                    # device_put to the device params already live on
+                    # returns a handle on the SAME buffers — buffers the
+                    # donated learner update is about to consume.  Give the
+                    # slot private storage so actors never read donated-away
+                    # memory.
+                    fresh = jax.tree.map(jnp.copy, fresh)
+                self._param_slots[i] = (version, fresh)
+                sent += 1
+            span.set_metadata(sent=sent, skipped=skipped)
+        self.publishes_sent += sent
+        self.publishes_skipped += skipped
 
     # -------------------------------------------------------------- actor
 
@@ -494,9 +544,10 @@ class Sebulba:
                 carry, init,
             )
         actions, aux, new_carry = self.agent.act(params, obs, a_rng, carry)
-        buf = buffer_add(
-            buf, obs, actions, aux.logp, aux.extras, rew_disc, carry
-        )
+        with jax.named_scope("ring_write"):
+            buf = buffer_add(
+                buf, obs, actions, aux.logp, aux.extras, rew_disc, carry
+            )
         return actions, buf, rng, new_carry
 
     def _initial_carry(self, device):
@@ -574,6 +625,7 @@ class Sebulba:
         carry = self._initial_carry(device)  # recurrent state, or ()
         t = 0  # host mirror of the ring cursor (control flow only, no sync)
         last_version = 0
+        traj_version = 0  # params version the ring's first step acted with
         injector = handle.injector
 
         while self._actor_live(handle):
@@ -606,8 +658,10 @@ class Sebulba:
                 traj, buf = self._drain(buf, hd_dev, obs_dev)
                 t = 0
                 shards = self._shard_for_learners(traj)
-                if not self._queue_put(shards, handle):
+                if not self._queue_put(shards, handle, traj_version):
                     return  # stopping — the in-flight trajectory is dropped
+            if t == 0:
+                traj_version = version
             actions, buf, rng, carry = self._act_step(
                 params, buf, rng, obs_dev, hd_dev, carry
             )
@@ -657,11 +711,13 @@ class Sebulba:
                 carry, init,
             )
         actions, aux, new_carry = self.agent.act(params, obs, a_rng, carry)
-        buf = buffer_add(
-            buf, obs, actions, aux.logp, aux.extras, rew_disc, carry
-        )
-        env_state, ts = self._fleet.step(env_state, actions)
-        stats = self._fleet.update_stats(stats, ts)
+        with jax.named_scope("ring_write"):
+            buf = buffer_add(
+                buf, obs, actions, aux.logp, aux.extras, rew_disc, carry
+            )
+        with jax.named_scope("env_step"):
+            env_state, ts = self._fleet.step(env_state, actions)
+            stats = self._fleet.update_stats(stats, ts)
         # same discount convention as the host path: cfg.discount on live
         # steps, 0 across episode boundaries (the env's discount channel
         # supplies the boundary)
@@ -687,6 +743,7 @@ class Sebulba:
         buf = None
         t = 0
         last_version = 0
+        traj_version = 0
         injector = handle.injector
         try:
             while self._actor_live(handle):
@@ -707,8 +764,10 @@ class Sebulba:
                     # handle is the whole snapshot (no copy, no sync)
                     handle.stats = stats
                     shards = self._shard_for_learners(traj)
-                    if not self._queue_put(shards, handle):
+                    if not self._queue_put(shards, handle, traj_version):
                         return
+                if t == 0:
+                    traj_version = version
                 buf, rng, env_state, obs, rew_disc, carry, stats = (
                     self._device_act_step(
                         params, buf, rng, env_state, obs, rew_disc, carry,
@@ -720,7 +779,7 @@ class Sebulba:
         finally:
             handle.stats = stats
 
-    def _queue_put(self, shards, handle: ActorHandle) -> bool:
+    def _queue_put(self, shards, handle: ActorHandle, version: int) -> bool:
         """Blocking put that never silently drops a trajectory.
 
         Retries on a full queue (counting the blocked intervals so ``run``
@@ -730,21 +789,33 @@ class Sebulba:
         abandonment) can never leave the put spinning.  Only those exits
         drop the trajectory, and that drop is counted too.  Returns False
         when stopping.
+
+        The entry is ``(epoch, version, traj, shards)``: ``version`` is the
+        params version the trajectory's first step acted with (the learner
+        reads the policy lag from it) and ``traj`` the trajectory's id,
+        ``"<slot>r<incarnation>:<sequence>"``, which the learner's update
+        span repeats.
         """
+        traj = f"{handle.slot}r{handle.incarnation}:{handle.puts}"
         # retry granularity must beat the watchdog: a put blocked on the
         # learner heartbeats once per retry, so the retry interval has to
         # sit well inside the stall budget or back-pressure reads as a hang
         timeout = min(0.5, self.cfg.stall_timeout / 4)
-        while self._actor_live(handle):
-            try:
-                # epoch-tagged: the learner drops entries produced under
-                # a stale membership (see run's epoch check)
-                self._queue.put((self._epoch, shards), timeout=timeout)
-                handle.mark_put()
-                return True
-            except queue.Full:
-                handle.beat()  # blocked on the learner, not hung
-                handle.put_blocked += 1
+        with jax.profiler.TraceAnnotation(
+            "sebulba.actor.put", traj=traj, version=version
+        ):
+            while self._actor_live(handle):
+                try:
+                    # epoch-tagged: the learner drops entries produced
+                    # under a stale membership (see run's epoch check)
+                    self._queue.put(
+                        (self._epoch, version, traj, shards), timeout=timeout
+                    )
+                    handle.mark_put()
+                    return True
+                except queue.Full:
+                    handle.beat()  # blocked on the learner, not hung
+                    handle.put_blocked += 1
         handle.traj_dropped += 1
         return False
 
@@ -783,10 +854,15 @@ class Sebulba:
         pmean -> optimizer update.  Shared by the on-policy and replay
         learners so the gradient-step sequence exists once.
         """
-        grads, aux = jax.grad(loss_fn, has_aux=True)(params)
+        # the named scopes label the update's device ops in a profile;
+        # inside ``forward_backward`` JAX's own ``jvp(...)`` and
+        # ``transpose(jvp(...))`` prefixes tell the two passes apart
+        with jax.named_scope("forward_backward"):
+            grads, aux = jax.grad(loss_fn, has_aux=True)(params)
         grads = jax.lax.pmean(grads, "batch")
-        updates, opt_state = self.opt.update(grads, opt_state, params)
-        params = optim.apply_updates(params, updates)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = self.opt.update(grads, opt_state, params)
+            params = optim.apply_updates(params, updates)
         return params, opt_state, aux
 
     def _build_update(self, example: Trajectory):
@@ -1080,6 +1156,7 @@ ActorSupervisor`: a crashed actor restarts with exponential backoff
                 self._fault_plan.checkpoint_injector()
                 if self._fault_plan is not None else None
             ),
+            span="sebulba.learner.checkpoint",
         )
 
         if self._cluster is not None:
@@ -1089,6 +1166,7 @@ ActorSupervisor`: a crashed actor restarts with exponential backoff
         self.supervisor.start()
 
         updates = 0
+        lag_sum = lag_max = 0  # policy lag over the updates made
         last_metrics: dict = {}
         macc = None  # device-resident metrics accumulator (init at 1st update)
         replay_state = None
@@ -1115,7 +1193,10 @@ ActorSupervisor`: a crashed actor restarts with exponential backoff
                 try:
                     # short poll so supervision stays responsive even when
                     # no actor is producing
-                    epoch_tag, shards = self._queue.get(timeout=0.5)
+                    with jax.profiler.TraceAnnotation("sebulba.learner.get"):
+                        epoch_tag, version, traj_id, shards = self._queue.get(
+                            timeout=0.5
+                        )
                 except queue.Empty:
                     # re-poll before judging progress: the snapshot from the
                     # top of the iteration is up to a get-timeout stale, and
@@ -1144,6 +1225,13 @@ ActorSupervisor`: a crashed actor restarts with exponential backoff
                     # rather than train across the reshard boundary
                     self.stale_epoch_trajs += 1
                     continue
+                # with replay, the trajectory is the one this update
+                # inserts; the batch it trains on is sampled from the buffer
+                lag = self._params_version - version
+                span = jax.profiler.TraceAnnotation(
+                    "sebulba.learner.update", traj=traj_id, version=version,
+                    lag=lag,
+                )
                 if self._replay is not None:
                     if replay_state is None:
                         replay_state = self._replay.init(shards)
@@ -1167,45 +1255,54 @@ ActorSupervisor`: a crashed actor restarts with exponential backoff
                             self._update_off_core, params, opt_state,
                             replay_state, shards, key, jnp.int32(0),
                         )[3])
-                    params, opt_state, replay_state, macc = self._update_off(
-                        params, opt_state, replay_state, shards, macc, key,
-                        jnp.int32(updates),
-                    )
+                    with span:
+                        params, opt_state, replay_state, macc = (
+                            self._update_off(
+                                params, opt_state, replay_state, shards,
+                                macc, key, jnp.int32(updates),
+                            )
+                        )
                 else:
                     update, core = self._get_update(shards)
                     if macc is None:
                         macc = self._fresh_macc(jax.eval_shape(
                             core, params, opt_state, shards
                         )[2])
-                    params, opt_state, macc = update(
-                        params, opt_state, shards, macc
-                    )
+                    with span:
+                        params, opt_state, macc = update(
+                            params, opt_state, shards, macc
+                        )
                 self._publish_params(params)
                 updates += 1
+                lag_sum += lag
+                lag_max = max(lag_max, lag)
                 ckpt.maybe_save(
                     params, param_version=self._params_version,
                     updates=base_updates + updates,
                     frames=base_frames + self.frames,
                 )
                 if log_every and updates % log_every == 0:
-                    m = self._drain_macc(macc)
-                    if m is not None:
-                        last_metrics = m
-                        macc = self._fresh_macc()
-                    if self._fleet is not None:
-                        _, ret = self._scenario_snapshot()
-                    else:
-                        ret = (
-                            np.mean(self.episode_returns)
-                            if self.episode_returns else float("nan")
+                    with jax.profiler.TraceAnnotation("sebulba.learner.log"):
+                        m = self._drain_macc(macc)
+                        if m is not None:
+                            last_metrics = m
+                            macc = self._fresh_macc()
+                        if self._fleet is not None:
+                            _, ret = self._scenario_snapshot()
+                        else:
+                            ret = (
+                                np.mean(self.episode_returns)
+                                if self.episode_returns else float("nan")
+                            )
+                        print(
+                            f"update {updates} frames {self.frames} "
+                            f"return {ret:.2f} "
+                            f"policy_lag={lag_sum / updates:.2f}/{lag_max} " +
+                            " ".join(
+                                f"{k}={v:.3f}"
+                                for k, v in last_metrics.items()
+                            )
                         )
-                    print(
-                        f"update {updates} frames {self.frames} "
-                        f"return {ret:.2f} " +
-                        " ".join(
-                            f"{k}={v:.3f}" for k, v in last_metrics.items()
-                        )
-                    )
         finally:
             self._stop.set()
             if self._cluster is not None:
@@ -1282,6 +1379,10 @@ ActorSupervisor`: a crashed actor restarts with exponential backoff
                 else 0
             ),
             checkpoints_saved=ckpt.saved,
+            # params versions between the one a trajectory's first step
+            # acted with and the one its update starts from
+            policy_lag_mean=lag_sum / updates if updates else 0.0,
+            policy_lag_max=lag_max,
         )
 
     def fit(

@@ -86,6 +86,7 @@ class ActorHandle:
         self.cancel = threading.Event()
         self.heartbeat = time.monotonic()
         self.frames = 0
+        self.puts = 0  # trajectories handed to the learner
         self.put_blocked = 0
         self.traj_dropped = 0
         self.stats = None  # device-env FleetStats snapshot
@@ -102,10 +103,11 @@ class ActorHandle:
         self.heartbeat = time.monotonic()
 
     def mark_put(self) -> None:
-        """Stamp the first successful trajectory put (and heartbeat).  The
-        first-put stamp pairs with the previous incarnation's ``died_at``
-        to measure recovery latency."""
+        """Count a successful trajectory put and stamp the first one (and
+        heartbeat).  The first-put stamp pairs with the previous
+        incarnation's ``died_at`` to measure recovery latency."""
         self.heartbeat = time.monotonic()
+        self.puts += 1
         if self.first_put_at is None:
             self.first_put_at = self.heartbeat
 

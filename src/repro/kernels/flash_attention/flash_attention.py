@@ -140,5 +140,6 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, h), jnp.float32),  # acc: weighted values
         ],
         interpret=interpret,
+        name="flash_attention",
     )(qh, kh, vh)
     return out.transpose(0, 2, 1, 3)  # back to (B, T, H, h)

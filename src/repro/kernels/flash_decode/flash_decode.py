@@ -159,6 +159,7 @@ def flash_decode_pallas(
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, h), q.dtype),
         interpret=interpret,
+        name="flash_decode",
     )(_pos_vector(pos, B), qh, k_cache, v_cache)
     return out.reshape(B, 1, H, h)
 
@@ -205,6 +206,7 @@ def flash_decode_pallas_paged(
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, G, h), q.dtype),
         interpret=interpret,
+        name="flash_decode_paged",
     )(
         _pos_vector(pos, B),
         jnp.asarray(block_tables, jnp.int32),

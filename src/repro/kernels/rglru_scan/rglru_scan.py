@@ -80,5 +80,6 @@ def rglru_scan_pallas(
             pltpu.VMEM((bt, bw), jnp.float32),
         ],
         interpret=interpret,
+        name="rglru_scan",
     )(x, a, gate_i)
     return y, y[:, -1].astype(jnp.float32)

@@ -136,5 +136,6 @@ def ssd_scan_pallas(
         ],
         scratch_shapes=[pltpu.VMEM((P, N), jnp.float32)],
         interpret=interpret,
+        name="ssd_scan",
     )(xh, dth, A.astype(jnp.float32), Bm, Cm)
     return y.transpose(0, 2, 1, 3), s_final

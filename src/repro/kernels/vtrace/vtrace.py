@@ -88,6 +88,7 @@ def vtrace_pallas(
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct((T, B_pad), jnp.float32)] * 2,
         interpret=interpret,
+        name="vtrace",
     )(
         cols(log_rhos), cols(discounts), cols(rewards), cols(values),
         cols(bootstrap_value),

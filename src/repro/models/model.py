@@ -555,8 +555,26 @@ class Model:
                 f"block_tables requires a dense/moe model, not {cfg.family}"
             )
         x = self._embed_decode(params, tokens, pos)
-        new_cache = {}
+        # the named scopes label the decode step's device ops in a profile
+        with jax.named_scope("decode_layers"):
+            x, new_cache = self._decode_layers(
+                params, cache, x, pos, block_tables
+            )
+        with jax.named_scope("unembed"):
+            x = layers.rms_norm(params["final_norm"], x, cfg.rms_norm_eps)
+            logits = layers.unembed(params["embedding"], x)
+        values = jnp.einsum(
+            "btd,dk->btk", x, params["value_head"]["w"].astype(x.dtype)
+        )[..., 0].astype(jnp.float32)
+        return logits, values, new_cache
 
+    def _decode_layers(
+        self, params: Params, cache: Params, x: jax.Array, pos: jax.Array,
+        block_tables: jax.Array | None,
+    ) -> tuple[jax.Array, Params]:
+        """Every layer of one decode step -> (hidden, new cache)."""
+        cfg = self.cfg
+        new_cache = {}
         if cfg.family in ("dense", "moe") and self.stacked:
             def step(p, c, h):
                 h, c2 = tf.attn_sublayer_decode(
@@ -642,13 +660,7 @@ class Model:
                     new_cache[f"layer_{i}"] = c2
         else:
             raise ValueError(cfg.family)
-
-        x = layers.rms_norm(params["final_norm"], x, cfg.rms_norm_eps)
-        logits = layers.unembed(params["embedding"], x)
-        values = jnp.einsum(
-            "btd,dk->btk", x, params["value_head"]["w"].astype(x.dtype)
-        )[..., 0].astype(jnp.float32)
-        return logits, values, new_cache
+        return x, new_cache
 
     # ---------------------------------------------------------- prefill step
 

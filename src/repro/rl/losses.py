@@ -134,10 +134,11 @@ def weighted_impala_loss(
     """
     target_logp = log_prob(logits, actions)
     log_rhos = target_logp - behaviour_logp
-    vt = vtrace(
-        log_rhos, discounts, rewards, values, bootstrap_value,
-        clip_rho=clip_rho, clip_c=clip_c,
-    )
+    with jax.named_scope("vtrace"):
+        vt = vtrace(
+            log_rhos, discounts, rewards, values, bootstrap_value,
+            clip_rho=clip_rho, clip_c=clip_c,
+        )
     if importance_weights is None:
         w = jnp.ones(values.shape[:1], jnp.float32)
     else:

@@ -232,7 +232,7 @@ def test_queue_put_retries_on_full_and_counts_blocked():
     result = {}
 
     def put():
-        result["ok"] = seb._queue_put("shards", handle)
+        result["ok"] = seb._queue_put("shards", handle, 3)
         done.set()
 
     t = threading.Thread(target=put, daemon=True)
@@ -242,8 +242,11 @@ def test_queue_put_retries_on_full_and_counts_blocked():
     assert seb._queue.get() == "occupying"  # learner frees a slot
     assert done.wait(timeout=5.0)
     # puts are tagged with the membership epoch at put time (multi-host
-    # elasticity: the learner drops trajectories that straddle a reshard)
-    assert result["ok"] and seb._queue.get() == (seb._epoch, "shards")
+    # elasticity: the learner drops trajectories that straddle a reshard),
+    # the params version the trajectory acted with (the policy lag) and
+    # the trajectory's id: slot 0, incarnation 0, its first put
+    assert result["ok"] and seb._queue.get() == (seb._epoch, 3, "0r0:0",
+                                                 "shards")
     assert handle.traj_dropped == 0
     assert handle.first_put_at is not None  # recovery-latency stamp landed
 
@@ -253,7 +256,7 @@ def test_queue_put_drops_only_on_stop():
     seb._queue.put("occupying")
     seb._stop.set()
     handle = _handle(seb)
-    assert seb._queue_put("shards", handle) is False
+    assert seb._queue_put("shards", handle, 3) is False
     assert handle.traj_dropped == 1
 
 
@@ -268,7 +271,7 @@ def test_queue_put_unblocks_on_watchdog_cancel():
     result = {}
 
     def put():
-        result["ok"] = seb._queue_put("shards", handle)
+        result["ok"] = seb._queue_put("shards", handle, 3)
         done.set()
 
     t = threading.Thread(target=put, daemon=True)

@@ -13,6 +13,7 @@ test worker imports every test file.
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -56,14 +57,27 @@ def _compiled_text(fn, sharding, *shapes) -> str:
     return text
 
 
+def _kernels(text: str) -> list[str]:
+    """The names of the program's Pallas kernels: each ``pallas_call``
+    compiles to one custom call named ``<name>.<n>`` after its ``name=``,
+    the name its ops carry in a device trace."""
+    return [
+        re.sub(r"\.\d+$", "", m)
+        for m in re.findall(
+            r"^\s*(?:ROOT )?%([\w.-]+) = .*custom-call\(.*"
+            r'custom_call_target="tpu_custom_call"', text, re.M)
+    ]
+
+
 def test_flash_decode_compiles_for_v5e(one_chip):
     from repro.kernels.flash_decode.flash_decode import flash_decode_pallas
 
-    _compiled_text(
+    text = _compiled_text(
         flash_decode_pallas, one_chip,
         ((B, 1, H, h), BF16), ((B, S, K, h), BF16), ((B, S, K, h), BF16),
         ((B,), jnp.int32),
     )
+    assert _kernels(text) == ["flash_decode"]
 
 
 def test_flash_decode_paged_compiles_for_v5e(one_chip):
@@ -74,11 +88,12 @@ def test_flash_decode_paged_compiles_for_v5e(one_chip):
     bs = 16
     nb = S // bs
     pool = (1 + B * nb, bs, K, h)
-    _compiled_text(
+    text = _compiled_text(
         flash_decode_pallas_paged, one_chip,
         ((B, 1, H, h), BF16), (pool, BF16), (pool, BF16),
         ((B, nb), jnp.int32), ((B,), jnp.int32),
     )
+    assert _kernels(text) == ["flash_decode_paged"]
 
 
 @pytest.mark.parametrize("batch", [32, 256])
@@ -86,9 +101,10 @@ def test_vtrace_compiles_for_v5e(one_chip, batch):
     from repro.kernels.vtrace.vtrace import vtrace_pallas
 
     bt = ((batch, 20), jnp.float32)
-    _compiled_text(
+    text = _compiled_text(
         vtrace_pallas, one_chip, bt, bt, bt, bt, ((batch,), jnp.float32)
     )
+    assert _kernels(text) == ["vtrace"]
 
 
 def test_impala_loss_grad_through_pallas_vtrace_compiles(one_chip, monkeypatch):
@@ -114,25 +130,47 @@ def test_impala_loss_grad_through_pallas_vtrace_compiles(one_chip, monkeypatch):
         return jax.grad(total, argnums=(0, 1))(logits, values)
 
     bt = ((batch, T), jnp.float32)
-    _compiled_text(
+    text = _compiled_text(
         grad, one_chip, ((batch, T, A), jnp.float32), bt,
         ((batch, T), jnp.int32), bt, bt, bt, ((batch,), jnp.float32),
     )
+    # the benchmark's vtrace_roofline finds the kernel's op by this pattern
+    from bench import common
+
+    reader = common.load_module(
+        common.BENCH / "metrics" / "vtrace_roofline.py")
+    assert _kernels(text) == ["vtrace"]
+    assert re.search(reader.KERNEL, _kernels(text)[0])
 
 
 def test_rglru_scan_compiles_for_v5e(one_chip):
     from repro.kernels.rglru_scan.rglru_scan import rglru_scan_pallas
 
     x = ((1, 2048, 2560), BF16)  # recurrentgemma-2b lru width
-    _compiled_text(rglru_scan_pallas, one_chip, x, x, x)
+    text = _compiled_text(rglru_scan_pallas, one_chip, x, x, x)
+    assert _kernels(text) == ["rglru_scan"]
 
 
 def test_ssd_scan_compiles_for_v5e(one_chip):
     from repro.kernels.ssd_scan.ssd_scan import ssd_scan_pallas
 
     T, Hs, P, N = 2048, 64, 64, 128  # mamba2-1.3b heads, head dim, state
-    _compiled_text(
+    text = _compiled_text(
         ssd_scan_pallas, one_chip,
         ((1, T, Hs, P), BF16), ((1, T, Hs), jnp.float32),
         ((Hs,), jnp.float32), ((1, T, N), BF16), ((1, T, N), BF16),
     )
+    assert _kernels(text) == ["ssd_scan"]
+
+
+def test_flash_attention_compiles_for_v5e(one_chip):
+    from repro.kernels.flash_attention.flash_attention import (
+        flash_attention_pallas,
+    )
+
+    T = 1024  # a qwen2-1.5b prefill chunk: 12 query heads over 2 kv heads
+    text = _compiled_text(
+        flash_attention_pallas, one_chip,
+        ((1, T, H, h), BF16), ((1, T, K, h), BF16), ((1, T, K, h), BF16),
+    )
+    assert _kernels(text) == ["flash_attention"]
