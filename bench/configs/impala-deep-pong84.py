@@ -148,3 +148,12 @@ def flops_per_frame(cfg: dict, traffic: dict) -> float:
     backward (3x forward), and the bootstrap forward shared by the T
     frames of a row."""
     return frame_flops(cfg) * (1.0 + 3.0 + 1.0 / traffic["trajectory_length"])
+
+
+def act_step_cost(cfg: dict, traffic: dict) -> tuple[float, float]:
+    """(operations, bytes) one act step needs at least: the forward pass
+    of the actor batch, and every float32 weight and one float32 batch of
+    frames read once."""
+    B = traffic["actor_batch_size"]
+    frame = cfg["frame_height"] * cfg["frame_width"] * cfg["frame_channels"]
+    return B * frame_flops(cfg), float(4 * (param_count(cfg) + B * frame))

@@ -8,8 +8,12 @@ helper thread with a frame budget that stays unreached until the window
 has closed (``_Budget``), and the learner's update is wrapped (``Probe``)
 to count updates and to put one tiny program behind each of them, whose
 completion marks the update's completion on the device.  The rate is the
-learner updates that completed between two such marks, times the frames
-each one trains on, over the time between the marks.
+frames the actors acted between two such marks over the time between them
+(``window``).  It equals the frames trained plus the change in the backlog
+(the frames acted but not yet trained), and the backlog is bounded by the
+queue, the actors' rings and the trajectory the learner holds
+(``max_backlog``): ``correct`` fails a run whose backlog at a mark lies
+above that bound, so frames that the learner drops or skips cannot count.
 
 What ``correct`` compares.  The same ``Sebulba`` object that set-up
 drives through its first updates runs the window.  The probe keeps host
@@ -40,6 +44,7 @@ import gc
 import math
 import threading
 import time
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -210,6 +215,39 @@ def grad_norms_from_state(opt_state, spec: dict) -> dict:
     raise ValueError("optimizer state has no moment to read the gradient from")
 
 
+class Mark(NamedTuple):
+    updates: int  # learner updates completed on the device
+    t: float  # the host clock then
+    acted: int  # frames the actors had acted by then (``Sebulba.frames``)
+
+
+def window(m0: Mark, m1: Mark, frames_per_update: int) -> dict:
+    """The rate between two marks: the frames acted between them over the
+    time between them.  That is the frames trained plus the change in the
+    backlog, the frames acted but not yet trained (``acted - updates x
+    frames per update``): a queue that fills or drains inside the window
+    moves the trained count by whole trajectories and this rate by nothing.
+    While the backlog stays within ``max_backlog`` the two rates differ by
+    less than that bound over the window.  The trained count and both
+    backlogs are kept to check the rate by."""
+    b0 = m0.acted - m0.updates * frames_per_update
+    b1 = m1.acted - m1.updates * frames_per_update
+    return {"k0": m0.updates, "k1": m1.updates, "t0": m0.t, "t1": m1.t,
+            "trained_frames": (m1.updates - m0.updates) * frames_per_update,
+            "backlog_frames": [b0, b1],
+            "train_frames_per_s": (m1.acted - m0.acted) / (m1.t - m0.t)}
+
+
+def max_backlog(traffic: dict) -> int:
+    """The most frames a sound program can have acted and not yet trained:
+    a full queue, one ring on each actor thread (filling, or full and
+    waiting to be put) and the trajectory the learner has taken from the
+    queue and not yet updated on."""
+    threads = traffic["num_actor_cores"] * traffic["threads_per_actor_core"]
+    trajs = traffic["queue_capacity"] + threads + 1
+    return trajs * traffic["actor_batch_size"] * traffic["trajectory_length"]
+
+
 class Probe:
     """Wraps the learner's donated update: counts updates, marks each
     one's completion with a tiny program on its output, and keeps host
@@ -318,19 +356,24 @@ class Session:
     def alive(self) -> bool:
         return self.thread.is_alive()
 
+    def mark(self) -> Mark:
+        """The probe's mark and the frames the actors had acted by then.
+        (The probe outlives the program for the reference, so it keeps no
+        hold on the program.)"""
+        k, t = self.probe.mark()
+        return Mark(k, t, self.seb.frames)
+
     def measure(self, seconds: float) -> dict:
-        """Frames trained per second between two completed updates about
-        ``seconds`` apart."""
-        k0, t0 = self.probe.mark()
-        while time.perf_counter() - t0 < seconds:
+        """Frames acted per second between two completed updates about
+        ``seconds`` apart (``window``)."""
+        m0 = self.mark()
+        while time.perf_counter() - m0.t < seconds:
             if not self.alive():
                 raise RuntimeError("fit ended inside the window")
             time.sleep(0.05)
-        k1, t1 = self.probe.mark()
+        m1 = self.mark()
         tr = self.cell.traffic
-        frames = (k1 - k0) * tr["actor_batch_size"] * tr["trajectory_length"]
-        return {"k0": k0, "k1": k1, "t0": t0, "t1": t1,
-                "train_frames_per_s": frames / (t1 - t0)}
+        return window(m0, m1, tr["actor_batch_size"] * tr["trajectory_length"])
 
     def stop(self) -> dict:
         self.budget.stop = True
@@ -519,7 +562,8 @@ def run(cell, seed: int, seconds: float, trace: bool, devices,
     setup_s = win["t0"] - t_start
     compiles = counter.between(win["t0"], win["t1"])
     common.log(f"window: updates {win['k0']}..{win['k1']} in "
-               f"{win['t1'] - win['t0']:.3f} s, {compiles} compiles inside")
+               f"{win['t1'] - win['t0']:.3f} s, backlog "
+               f"{win['backlog_frames']} frames, {compiles} compiles inside")
     traced = None
     if trace:
         traced = _traced_window(s, cell.spec["trace_seconds"])
@@ -541,6 +585,8 @@ def run(cell, seed: int, seconds: float, trace: bool, devices,
             "ok": readings[k] <= limits[k]}
         for k in limits
     }
+    backlog, cap = max(win["backlog_frames"]), max_backlog(cell.traffic)
+    checks["backlog"] = {"value": backlog, "limit": cap, "ok": backlog <= cap}
     checks["actor_failures"] = {"value": failed, "limit": 0,
                                 "ok": failed == 0}
     checks["compiles_in_window"] = {"value": compiles, "limit": 0,
@@ -550,6 +596,10 @@ def run(cell, seed: int, seconds: float, trace: bool, devices,
         "attempted": win["k1"] - win["k0"],
         "failed": failed,
         "device": device,
+        # the raw count and the backlog at both marks, to check the rate by
+        "window": {"seconds": win["t1"] - win["t0"],
+                   "trained_frames": win["trained_frames"],
+                   "backlog_frames": win["backlog_frames"]},
     }
     if trace:
         metrics, extra, breakdown = _per_layer(cell, traced, win, devices,

@@ -24,14 +24,21 @@ import pytest  # noqa: E402
 
 jax.config.update("jax_enable_compilation_cache", False)
 
+TINY_QWEN2 = {"hidden_size": 64, "intermediate_size": 128,
+              "num_attention_heads": 4, "num_key_value_heads": 2,
+              "head_dim": 16, "num_hidden_layers": 2, "vocab_size": 256,
+              "stall_timeout_s": 300.0}
 TINY = {
     "lmrl-qwen2-copy64": {
-        "cfg": {"hidden_size": 64, "intermediate_size": 128,
-                "num_attention_heads": 4, "num_key_value_heads": 2,
-                "head_dim": 16, "num_hidden_layers": 2, "vocab_size": 256,
-                "stall_timeout_s": 300.0},
+        "cfg": TINY_QWEN2,
         "traffic": {"prompt_len": 4, "trajectory_length": 8,
                     "actor_batch_size": 4},
+        "spec": {"warm_updates": 5},
+    },
+    "lmrl-qwen2-learn": {
+        "cfg": TINY_QWEN2,
+        "traffic": {"prompt_len": 2, "trajectory_length": 4,
+                    "actor_batch_size": 8},
         "spec": {"warm_updates": 5},
     },
     "impala-deep-pong84-x4": {
@@ -44,10 +51,11 @@ TINY = {
 
 
 # The four-chip IMPALA cell (1 actor : 3 learner chips) is not in
-# BENCHMARK.json yet: no four-chip machine was free to measure it.  Its
-# configuration and traffic files are; the tests describe the cell here so
-# that its reference and its faults over three learner devices stay
-# checked.
+# BENCHMARK.json: its limits need a number that tells half the batch and
+# one learner's shard from sound runs, read on four chips.  Its
+# configuration and traffic files are there; the tests describe the cell
+# here so that its reference and its faults over three learner devices
+# stay checked.
 X4 = "impala-deep-pong84-x4"
 X4_ENTRIES = {
     "configs": {"name": "impala-deep-pong84",

@@ -75,10 +75,37 @@ def test_configs_keep_published_widths():
     qwen = common.Cell("lmrl-qwen2-copy64")
     n = qwen.cfg_module.param_count(qwen.cfg)
     assert math.isclose(n, 0.982e9, rel_tol=0.01), n
-    impala = common.load_module(common.BENCH / "configs" / "impala-deep-pong84.py")
-    cfg = common.load_json(common.BENCH / "configs" / "impala-deep-pong84.json")
+    impala, cfg, _ = impala_files()
     assert impala.param_count(cfg) == 1_089_828
     assert impala.frame_flops(cfg) == pytest.approx(102.4e6, rel=0.01)
+
+
+def impala_files():
+    """The IMPALA configuration's module, its sizes and the four-chip
+    traffic, which the tests describe as a cell (conftest)."""
+    return (common.load_module(common.BENCH / "configs" / "impala-deep-pong84.py"),
+            common.load_json(common.BENCH / "configs" / "impala-deep-pong84.json"),
+            common.load_json(common.BENCH / "traffic" / "pong84-b96-split1to3.json"))
+
+
+def conv_flops(hw, cin, cout):
+    return 2 * hw * hw * 9 * cin * cout
+
+
+def test_impala_act_step_cost():
+    """The IMPALA act step's least work, against a hand count: the deep
+    network's convolutions (84 -> 42 -> 21 -> 11 after each pool, two
+    residual blocks of two convs a stage) and dense layers for each of the
+    96 frames, and the float32 weights plus one float32 frame batch."""
+    impala, cfg, traffic = impala_files()
+    frame = (conv_flops(84, 1, 16) + 4 * conv_flops(42, 16, 16)
+             + conv_flops(42, 16, 32) + 4 * conv_flops(21, 32, 32)
+             + conv_flops(21, 32, 32) + 4 * conv_flops(11, 32, 32)
+             + 2 * (11 * 11 * 32 * 256 + 256 * 3 + 256 * 1))
+    flops, nbytes = impala.act_step_cost(cfg, traffic)
+    assert traffic["actor_batch_size"] == 96
+    assert flops == 96 * frame
+    assert nbytes == 4 * 1_089_828 + 96 * 84 * 84 * 4
 
 
 def test_cpu_run_is_refused():

@@ -7,8 +7,6 @@ at a small size.  At that size the program's sound readings are not those
 of the chip (a few thousand bfloat16 weights round differently from
 1.5B), so each check's limit here is the cell's limit or three times the
 sound run's reading, whichever is larger; a fault has to read above both.
-The four-chip cell, which the tests describe (conftest), borrows the
-one-chip cell's limits.
 """
 
 import time
@@ -19,7 +17,7 @@ import pytest
 
 from bench.drivers import sebulba as drv
 
-CELLS = ["lmrl-qwen2-copy64", "impala-deep-pong84-x4"]
+CELLS = ["lmrl-qwen2-copy64", "lmrl-qwen2-learn", "impala-deep-pong84-x4"]
 
 
 def sound_readings(cell, devices, seed):
@@ -100,8 +98,32 @@ def fault_altered_token(monkeypatch):
         monkeypatch.setattr(cls, "act", act)
 
 
+def fault_dropped(monkeypatch):
+    """The learner takes every trajectory from the queue and trains on
+    every other one: the actors' count runs on, the backlog grows."""
+    import itertools
+
+    from repro.core.sebulba import Sebulba
+
+    orig = Sebulba.__init__
+
+    def init(self, *args, **kwargs):
+        orig(self, *args, **kwargs)
+        get, n = self._queue.get, itertools.count()
+
+        def get_every_other(*a, **kw):
+            while next(n) % 2:
+                get(*a, **kw)
+            return get(*a, **kw)
+
+        self._queue.get = get_every_other
+
+    monkeypatch.setattr(Sebulba, "__init__", init)
+
+
 FAULTS = {
     "unchanged": fault_unchanged,
+    "dropped": fault_dropped,
     "half_batch": fault_half_batch,
     "no_exchange": fault_no_exchange,
     "altered_token": fault_altered_token,

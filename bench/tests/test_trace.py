@@ -137,8 +137,32 @@ def test_recorded_metric_readers(recorded):
         100 * ctx.cfg_module.flops_per_frame(ctx.cfg, ctx.traffic) * 2000 / 197e12)
 
 
+def allreduce_trace() -> Trace:
+    """Chip 0 acts; chips 1-3 learn, two updates each, with the gradient
+    all-reduce inside each update: chip 3 is the slowest to exchange."""
+    ops, modules = {0: [("%fusion.1", 0, 100)]}, {0: []}
+    for dev, ar in ((1, 40), (2, 50), (3, 70)):
+        ops[dev], modules[dev] = [], []
+        for start in (1_000, 2_000):
+            modules[dev].append((f"jit_update({dev})", start, start + 500))
+            ops[dev] += [("%convolution.3", start, start + 300),
+                         ("%all-reduce.7 = f32[9] all-reduce(...)",
+                          start + 300, start + 300 + ar),
+                         ("%all-reduce.8 = f32[3] all-reduce(...)",
+                          start + 400, start + 410)]
+    return Trace(ops, modules, [])
+
+
+def test_allreduce_ms_on_three_learners():
+    ctx = fake_ctx(allreduce_trace(), chips=4, learners=[1, 2, 3])
+    # chip 3: (70 + 10) ns an update, two updates
+    assert ctx.cell.metric_reader("allreduce_ms").read(ctx) == pytest.approx(80e-6)
+    one = fake_ctx(allreduce_trace(), chips=4, learners=[1])
+    assert one.cell.metric_reader("allreduce_ms").read(one) is None
+
+
 def test_readers_find_nothing_on_an_empty_trace():
     ctx = fake_ctx(Trace({0: []}, {0: []}, []))
     for m in ("actor_step_ms", "learner_update_ms", "act_step_roofline",
-              "vtrace_roofline", "idle_share.train"):
+              "vtrace_roofline", "idle_share.train", "allreduce_ms"):
         assert ctx.cell.metric_reader(m).read(ctx) is None, m
