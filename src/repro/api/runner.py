@@ -24,6 +24,9 @@ keys):
                        actors observe; Anakin: update count)
     publishes_sent     actor-core param transfers dispatched (Sebulba)
     publishes_skipped  overlap-aware publish skips (Sebulba)
+    publishes_aliased  publishes to an actor core that is also a learner
+                       core: the slot takes the update's output, no copy
+                       (Sebulba)
     put_blocked        full-queue retry intervals on the actor side
     traj_dropped       trajectories dropped at shutdown
     replay_size        filled replay slots at exit (off-policy Sebulba)
@@ -92,6 +95,7 @@ RESULT_KEYS = (
     "param_version",
     "publishes_sent",
     "publishes_skipped",
+    "publishes_aliased",
     "put_blocked",
     "traj_dropped",
     "replay_size",
@@ -115,6 +119,7 @@ _COUNTER_DEFAULTS = {
     "param_version": 0,
     "publishes_sent": 0,
     "publishes_skipped": 0,
+    "publishes_aliased": 0,
     "put_blocked": 0,
     "traj_dropped": 0,
     "replay_size": 0,

@@ -20,16 +20,21 @@ Reproduces the paper's dataflow exactly:
     batch over the learner mesh and runs the same update on every learner
     core (shard_map), averaging gradients with jax.lax.pmean;
   * the learner update is built once per trajectory shape and cached, with
-    params, opt_state, the incoming trajectory shards, and the on-device
-    metrics accumulator all donated — the steady-state learner loop is one
-    XLA dispatch per update that reuses its buffers in place and never
-    syncs device->host (metrics drain to host only on ``log_every``
-    boundaries);
+    opt_state, the incoming trajectory shards, and the on-device metrics
+    accumulator donated — the steady-state learner loop is one XLA
+    dispatch per update that reuses its buffers in place and never syncs
+    device->host (metrics drain to host only on ``log_every``
+    boundaries).  Params are donated only where no actor reads them: when
+    every actor core is off the learner mesh;
   * after each update the learner publishes fresh parameters
     device-to-device to every actor core through a lock-free versioned
     params slot (device_put dispatches async, so the publish never blocks
     the learner); actor threads pick the slot up before their next step.
-    The publish is overlap-aware: a core that has not consumed its last
+    An actor core that is also a learner core gets a handle on the
+    update's own (undonated) output: no copy, no device program, no skip;
+    there the learner lets each update finish before it dispatches the
+    next, since a queued update would hold a params set of its own.  The
+    publish is overlap-aware: a core that has not consumed its last
     publish is skipped (``SebulbaConfig.publish_throttle``), so params
     bytes only move when an actor will actually act on them.
 
@@ -110,7 +115,9 @@ name:
                             trajectory the update inserts; the batch it
                             trains on is sampled from the buffer)
   sebulba.learner.publish   the parameter publish; stats ``version``,
-                            ``sent`` and ``skipped`` (actor cores)
+                            ``sent``, ``skipped`` and ``aliased`` (actor
+                            cores; ``aliased``: sent to a learner core,
+                            as a handle on the update's output)
   sebulba.learner.checkpoint  a checkpoint write
   sebulba.learner.log       the ``log_every`` metrics drain and print
 
@@ -187,7 +194,9 @@ class SebulbaConfig:
     # still unconsumed (the actor acts with the standing slot and the next
     # publish lands instead) — fewer transfers at the cost of up to one
     # actor-pickup interval of extra policy lag when the learner outpaces
-    # actors; V-trace absorbs the lag.  False -> publish every update.
+    # actors; V-trace absorbs the lag.  A core that is also a learner core
+    # is never skipped: its publish moves no bytes.  False -> publish every
+    # update.
     publish_throttle: bool = True
     # recurrent agents only (R2D2, Kapturowski et al. 2019): unroll the
     # first ``burn_in`` steps of every trajectory with the stored carry but
@@ -370,7 +379,8 @@ class Sebulba:
                     f"({config.trajectory_length})"
                 )
         # learner updates are built lazily (they need the trajectory
-        # structure), cached per trajectory shape, and donated end to end
+        # structure), cached per trajectory shape, and donated (see
+        # ``_donate_state``)
         self._update_cache: dict = {}
         self._update_off = None
         self._update_off_core = None
@@ -409,12 +419,17 @@ class Sebulba:
         self._slot_consumed: list[int] = [0] * self.split.num_actors
         self.publishes_sent = 0
         self.publishes_skipped = 0
-        # degenerate topology (e.g. single-device CPU): an actor core that
-        # is also a learner core shares buffers with the donated update —
-        # publishes to it need their own storage (see _publish_params)
+        # slot writes that hand an actor core the update's output buffers
+        self.publishes_aliased = 0
+        # an actor core that is also a learner core (one chip, or a single
+        # CPU device) reads the learner's own params buffers through its
+        # slot (see _publish_params).  Donate params only where no actor
+        # reads them, opt_state always: both updates take the donated
+        # learner-state arguments (params=0, opt_state=1) from here.
         self._shared_devices = frozenset(self.split.actor_devices) & frozenset(
             self.split.learner_devices
         )
+        self._donate_state = (1,) if self._shared_devices else (0, 1)
         self._queue: queue.Queue = queue.Queue(maxsize=config.queue_capacity)
         self._stop = threading.Event()
         self.episode_returns: deque = deque(maxlen=256)
@@ -484,33 +499,35 @@ class Sebulba:
         the actor actually used, and the learner's V-trace correction
         absorbs this lag exactly as it absorbs queueing lag; set
         ``publish_throttle=False`` if minimum policy lag matters more than
-        publish bandwidth.
+        publish bandwidth.  A core that is also a learner core is never
+        skipped: its slot takes a handle on the learner's params, so the
+        publish moves no bytes, and a standing slot would keep an older
+        params set alive in device memory beside the learner's.
         """
         self._params_version += 1
         version = self._params_version
         throttle = self.cfg.publish_throttle and not force
-        sent = skipped = 0
+        sent = skipped = aliased = 0
         with jax.profiler.TraceAnnotation(
             "sebulba.learner.publish", version=version
         ) as span:
             for i, dev in enumerate(self.split.actor_devices):
-                if (throttle
+                shared = dev in self._shared_devices
+                if (throttle and not shared
                         and self._slot_consumed[i] < self._param_slots[i][0]):
                     skipped += 1
                     continue
-                fresh = jax.device_put(params, dev)
-                if dev in self._shared_devices:
-                    # device_put to the device params already live on
-                    # returns a handle on the SAME buffers — buffers the
-                    # donated learner update is about to consume.  Give the
-                    # slot private storage so actors never read donated-away
-                    # memory.
-                    fresh = jax.tree.map(jnp.copy, fresh)
-                self._param_slots[i] = (version, fresh)
+                # onto a learner core, device_put returns a handle on the
+                # update's own output buffers: no copy.  The update does
+                # not donate params on such a topology (``_donate_state``),
+                # so the slot stays live through the next update.
+                self._param_slots[i] = (version, jax.device_put(params, dev))
                 sent += 1
-            span.set_metadata(sent=sent, skipped=skipped)
+                aliased += shared
+            span.set_metadata(sent=sent, skipped=skipped, aliased=aliased)
         self.publishes_sent += sent
         self.publishes_skipped += skipped
+        self.publishes_aliased += aliased
 
     # -------------------------------------------------------------- actor
 
@@ -921,10 +938,11 @@ class Sebulba:
         shape -> (jitted update, core).
 
         Built once per (structure, shapes, dtypes) key and jitted with
-        ``donate_argnums`` covering params, opt_state, the trajectory
-        shards (they alias the actor ring's D2D copies and are dead after
-        the grad step), and the metrics accumulator — the steady-state
-        learner update reuses all its buffers in place.
+        ``donate_argnums`` covering opt_state, the trajectory shards (they
+        alias the actor ring's D2D copies and are dead after the grad
+        step), the metrics accumulator, and params where no actor reads
+        them (``_donate_state``) — the steady-state learner update reuses
+        those buffers in place.
         """
         key = self._traj_key(traj)
         entry = self._update_cache.get(key)
@@ -938,7 +956,10 @@ class Sebulba:
                 params, opt_state, metrics = core(params, opt_state, traj)
                 return params, opt_state, self._macc_add(macc, metrics)
 
-            entry = (jax.jit(update, donate_argnums=(0, 1, 2, 3)), core)
+            entry = (
+                jax.jit(update, donate_argnums=(*self._donate_state, 2, 3)),
+                core,
+            )
             self._update_cache[key] = entry
         return entry
 
@@ -983,10 +1004,11 @@ class Sebulba:
         """One fused device step: insert the online shard into the local
         replay ring, sample a replay shard, train on the concatenated mixed
         batch with PER importance weights, write TD priorities back.
-        Params, opt_state, the replay ring, and the metrics accumulator are
-        all donated, so the whole learner state updates in place and never
-        leaves the learner cores.  Returns (jitted update, core) — the core
-        exists so ``run`` can ``eval_shape`` the metrics structure.
+        The optimizer state, the replay ring, the metrics accumulator, and
+        params where no actor reads them (``_donate_state``) are donated,
+        so the learner state updates in place and never leaves the learner
+        cores.  Returns (jitted update, core) — the core exists so ``run``
+        can ``eval_shape`` the metrics structure.
         """
         cfg = self.cfg
         rcfg = cfg.replay
@@ -1062,7 +1084,9 @@ class Sebulba:
             )
             return params, opt_state, rstate, self._macc_add(macc, metrics)
 
-        return jax.jit(update, donate_argnums=(0, 1, 2, 4)), core
+        return jax.jit(
+            update, donate_argnums=(*self._donate_state, 2, 4)
+        ), core
 
     def _scenario_snapshot(self):
         """Aggregate the per-thread FleetStats snapshots into the
@@ -1273,6 +1297,13 @@ ActorSupervisor`: a crashed actor restarts with exponential backoff
                             params, opt_state, shards, macc
                         )
                 self._publish_params(params)
+                if self._shared_devices:
+                    # params are not donated here, so an update queued on
+                    # the device holds a fresh params set of its own: let
+                    # the update finish before the next is dispatched, and
+                    # the device holds no more params sets than donation
+                    # left it (the actors' queued act steps keep it busy)
+                    jax.block_until_ready(params)
                 updates += 1
                 lag_sum += lag
                 lag_max = max(lag_max, lag)
@@ -1351,6 +1382,7 @@ ActorSupervisor`: a crashed actor restarts with exponential backoff
             param_version=self._params_version,
             publishes_sent=self.publishes_sent,
             publishes_skipped=self.publishes_skipped,
+            publishes_aliased=self.publishes_aliased,
             # learner back-pressure / shutdown accounting (the actor loop
             # retries full-queue puts instead of dropping); sums span every
             # incarnation the supervisor ever spawned

@@ -359,16 +359,17 @@ def test_result_schema_unified_across_all_paths():
         missing = set(api.RESULT_KEYS) - set(out)
         assert not missing, f"{name} result missing {missing}"
         for key in ("updates", "frames", "param_version", "publishes_sent",
-                    "publishes_skipped", "put_blocked", "traj_dropped",
-                    "replay_size", "checkpoints_saved", "policy_lag_max"):
+                    "publishes_skipped", "publishes_aliased", "put_blocked",
+                    "traj_dropped", "replay_size", "checkpoints_saved",
+                    "policy_lag_max"):
             assert isinstance(out[key], int), (name, key, type(out[key]))
         assert isinstance(out["policy_lag_mean"], float), name
     # architecture-absent counters are zeros, not gaps
     assert out_on["replay_size"] == 0
     assert out_off["replay_size"] > 0
-    for key in ("publishes_sent", "publishes_skipped", "put_blocked",
-                "traj_dropped", "replay_size", "policy_lag_mean",
-                "policy_lag_max"):
+    for key in ("publishes_sent", "publishes_skipped", "publishes_aliased",
+                "put_blocked", "traj_dropped", "replay_size",
+                "policy_lag_mean", "policy_lag_max"):
         assert out_ank[key] == 0
     assert out_ank["param_version"] == out_ank["updates"]
 

@@ -2,14 +2,21 @@
 pinned bit-exact against the un-donated pre-cache path, compiles exactly
 once per trajectory shape, accumulates metrics on device, and the
 overlap-aware versioned publish never skips forever, never goes backwards,
-and never hands an actor a torn or donated-away slot."""
+and never hands an actor a torn or donated-away slot.  Where an actor core
+is also a learner core the update keeps params and the publish hands the
+slot the update's own output, with no copy."""
 
+import json
+import os
 import queue
+import subprocess
+import sys
 import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro import optim
 from repro.agents import BatchedMLPActorCritic
@@ -92,23 +99,72 @@ def test_donated_cached_update_bit_exact_vs_precache_path():
         np.testing.assert_allclose(drained[k], ref_means[k], rtol=1e-6)
 
 
-def test_donated_update_runs_in_place():
-    """Donation must consume params/opt_state and reuse their storage (the
-    learner state stops double-buffering)."""
+def _donation(seb) -> dict:
+    """One update on copies of ``seb``'s initial state -> whether it
+    consumed params and opt_state, and whether it wrote each in place."""
     B, T = 6, 3
-    seb = _make_seb(B, T)
     params0, opt0 = seb.init(jax.random.key(0), (4,))
     traj = _make_traj(seb, B, T, 0)
     update, core = seb._get_update(traj)
     macc = seb._fresh_macc(jax.eval_shape(core, params0, opt0, traj)[2])
 
     p, o = _copy(params0), _copy(opt0)
-    in_ptrs = [l.unsafe_buffer_pointer() for l in jax.tree.leaves((p, o))]
-    old_leaf = jax.tree.leaves(p)[0]
+    p_in, o_in = jax.tree.leaves(p), jax.tree.leaves(o)
+    p_ptrs = [leaf.unsafe_buffer_pointer() for leaf in p_in]
+    o_ptrs = [leaf.unsafe_buffer_pointer() for leaf in o_in]
     p2, o2, _ = update(p, o, traj, macc)
-    assert old_leaf.is_deleted(), "donated params must be consumed"
-    out_ptrs = [l.unsafe_buffer_pointer() for l in jax.tree.leaves((p2, o2))]
-    assert in_ptrs == out_ptrs, "donation must reuse the state storage"
+    out_ptrs = [leaf.unsafe_buffer_pointer()
+                for leaf in jax.tree.leaves((p2, o2))]
+    return {
+        "params_consumed": all(leaf.is_deleted() for leaf in p_in),
+        "params_live": not any(leaf.is_deleted() for leaf in p_in),
+        "state_in_place": p_ptrs + o_ptrs == out_ptrs,
+        "opt_consumed": all(leaf.is_deleted() for leaf in o_in),
+        "opt_storage_reused": set(o_ptrs) <= set(out_ptrs),
+    }
+
+
+def _on_two_devices(check: str) -> dict:
+    """``check(_make_seb())`` (a function of this module) in a subprocess
+    that sees two CPU devices: one actor core and one learner core, no
+    device shared."""
+    here = os.path.dirname(os.path.abspath(__file__))
+    src = os.path.join(os.path.dirname(here), "src")
+    code = (
+        f"import json, sys; sys.path[:0] = [{here!r}, {src!r}]; "
+        "import jax, test_learner_pipeline as t; "
+        "seb = t._make_seb(); "
+        "assert len(jax.devices()) == 2 and not seb._shared_devices; "
+        f"print(json.dumps(t.{check}(seb)))"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=2")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=300, env=env)
+    assert proc.returncode == 0, proc.stderr[-3000:]
+    return json.loads(proc.stdout.splitlines()[-1])
+
+
+@pytest.mark.parametrize("topology", ["disjoint", "shared"])
+def test_donated_update_runs_in_place(topology):
+    """Donation must consume opt_state and reuse its storage (the learner
+    state stops double-buffering).  Where no actor core is a learner core,
+    params are donated too and the whole state is rewritten in place,
+    leaf for leaf.  Where one is (the single CPU device), params stay live
+    for the actors' slot; the update writes them to fresh storage or to
+    the storage of an opt_state leaf of the same shape (XLA hands a
+    donated buffer to the first output that matches it)."""
+    if topology == "disjoint":
+        out = _on_two_devices("_donation")
+        assert out["params_consumed"], "donated params must be consumed"
+        assert out["state_in_place"], "donation must reuse the state storage"
+    else:
+        seb = _make_seb()
+        assert seb._shared_devices, "CPU test topology shares the device"
+        out = _donation(seb)
+        assert out["params_live"], "an actor may read params: keep them"
+    assert out["opt_consumed"], "donated opt_state must be consumed"
+    assert out["opt_storage_reused"], "donation must reuse opt_state's storage"
 
 
 def test_one_compile_per_trajectory_shape():
@@ -154,31 +210,58 @@ def test_metrics_accumulator_drains_means_and_resets():
 # ------------------------------------------------ overlap-aware publishing
 
 
-def test_publish_skips_unconsumed_slot_and_stays_monotone():
-    """A slow actor core: publishes while its slot is unconsumed must be
-    skipped (no transfer, slot untouched); once the actor stamps
-    consumption the next publish lands with a strictly higher version."""
-    seb = _make_seb()
+def _throttle(seb) -> dict:
+    """Four publishes while the actor core has not consumed its slot, then
+    one after it has -> whether each left the initial slot standing, the
+    versions the slot showed, and the counters."""
     params0, _ = seb.init(jax.random.key(0), (4,))  # forced initial publish
-    assert seb.publishes_sent == 1 and seb.publishes_skipped == 0
-    v0, slot0 = seb._param_slots[0]
-
-    observed = [v0]
-    for _ in range(4):  # learner outpaces the actor: all skipped
+    first = [seb.publishes_sent, seb.publishes_skipped]
+    versions, stood = [seb._param_slots[0][0]], []
+    slot0 = seb._param_slots[0][1]
+    for _ in range(4):  # the learner outpaces the actor
         seb._publish_params(params0)
-        version, slot = seb._param_slots[0]
-        observed.append(version)
-        assert slot is slot0, "skipped publish must leave the slot standing"
-    assert seb.publishes_sent == 1 and seb.publishes_skipped == 4
-    assert seb._params_version == 5  # versions advance even when skipped
-
+        versions.append(seb._param_slots[0][0])
+        stood.append(seb._param_slots[0][1] is slot0)
+    unconsumed = [seb.publishes_sent, seb.publishes_skipped,
+                  seb._params_version]
     seb._slot_consumed[0] = seb._param_slots[0][0]  # actor picks the slot up
     seb._publish_params(params0)
-    version, slot = seb._param_slots[0]
-    observed.append(version)
-    assert slot is not slot0 and version == 6
-    assert seb.publishes_sent == 2
-    assert observed == sorted(observed), "actor-visible versions must be monotone"
+    versions.append(seb._param_slots[0][0])
+    return {
+        "first": first, "stood": stood, "unconsumed": unconsumed,
+        "picked_up": [seb.publishes_sent, seb.publishes_skipped],
+        "last_stood": seb._param_slots[0][1] is slot0,
+        "versions": versions, "aliased": seb.publishes_aliased,
+    }
+
+
+@pytest.mark.parametrize("topology", ["disjoint", "shared"])
+def test_publish_skips_unconsumed_slot_and_stays_monotone(topology):
+    """A slow actor core: publishes while its slot is unconsumed must be
+    skipped (no transfer, slot untouched); once the actor stamps
+    consumption the next publish lands with a strictly higher version.  A
+    core that is also a learner core is never skipped: its publish moves
+    no bytes, and a standing slot would keep an older params set alive
+    beside the learner's."""
+    if topology == "disjoint":
+        out = _on_two_devices("_throttle")
+        assert out["stood"] == [True] * 4, (
+            "skipped publish must leave the slot standing"
+        )
+        # versions advance even when skipped
+        assert out["unconsumed"] == [1, 4, 5]
+        assert out["picked_up"] == [2, 4] and not out["last_stood"]
+        assert out["aliased"] == 0
+    else:
+        out = _throttle(_make_seb())
+        assert out["stood"] == [False] * 4
+        assert out["unconsumed"] == [5, 0, 5]
+        assert out["picked_up"] == [6, 0] and out["aliased"] == 6
+    assert out["first"] == [1, 0]
+    assert out["versions"][-1] == 6
+    assert out["versions"] == sorted(out["versions"]), (
+        "actor-visible versions must be monotone"
+    )
 
 
 def test_publish_throttle_off_publishes_every_update():
@@ -189,25 +272,102 @@ def test_publish_throttle_off_publishes_every_update():
     assert seb.publishes_sent == 6 and seb.publishes_skipped == 0
 
 
+def _leaves(tree):
+    return [np.asarray(leaf).copy() for leaf in jax.tree.leaves(tree)]
+
+
+def _assert_live_and_equal(tree, expected):
+    leaves = jax.tree.leaves(tree)
+    assert not any(leaf.is_deleted() for leaf in leaves), (
+        "the update consumed buffers an actor slot holds"
+    )
+    for leaf, want in zip(leaves, expected):
+        np.testing.assert_array_equal(np.asarray(leaf), want)
+
+
 def test_publish_slot_survives_donated_update_on_shared_device():
-    """Degenerate single-device topology: the published slot must own its
-    storage, so the donated learner update consuming params cannot
-    invalidate what actor threads are reading (device_put to the same
-    device aliases — the publish must copy)."""
+    """Degenerate single-device topology: the update does not donate
+    params, so a slot that aliases them stays live through it.  The
+    publish hands the slot the update's own output buffers (same pointers:
+    no copy), the next update leaves them live and unchanged, and
+    ``publishes_aliased`` counts both slot writes."""
     seb = _make_seb()
     assert seb._shared_devices, "CPU test topology shares the device"
     params0, opt0 = seb.init(jax.random.key(0), (4,))
     _version, slot_params = seb._param_slots[0]
-    slot_before = np.asarray(jax.tree.leaves(slot_params)[0]).copy()
+    slot_before = _leaves(slot_params)
 
     traj = _make_traj(seb, 6, 3, 0)
     update, core = seb._get_update(traj)
     macc = seb._fresh_macc(jax.eval_shape(core, params0, opt0, traj)[2])
-    update(params0, opt0, traj, macc)  # donates params0/opt0
+    p2, o2, macc = update(params0, opt0, traj, macc)
+    _assert_live_and_equal(slot_params, slot_before)
 
-    leaf = jax.tree.leaves(slot_params)[0]
-    assert not leaf.is_deleted(), "slot must not alias donated learner state"
-    np.testing.assert_array_equal(np.asarray(leaf), slot_before)
+    seb._publish_params(p2)
+    _version, slot_params = seb._param_slots[0]
+    assert [leaf.unsafe_buffer_pointer()
+            for leaf in jax.tree.leaves(slot_params)] == [
+        leaf.unsafe_buffer_pointer() for leaf in jax.tree.leaves(p2)
+    ], "the slot must alias the update's output, not copy it"
+    assert seb.publishes_sent == seb.publishes_aliased == 2
+
+    slot_before = _leaves(slot_params)
+    update(p2, o2, _make_traj(seb, 6, 3, 1), macc)
+    _assert_live_and_equal(slot_params, slot_before)
+
+
+def test_offpolicy_update_leaves_shared_slot_live():
+    """The replay (off-policy) update follows the same rule: on a shared
+    device it consumes the replay ring but not the params a slot holds."""
+    from repro.configs.base import ReplayConfig
+
+    seb = _make_seb(replay=ReplayConfig(capacity=12, sample_batch_size=6,
+                                        min_size=6))
+    assert seb._shared_devices, "CPU test topology shares the device"
+    params0, opt0 = seb.init(jax.random.key(0), (4,))
+    _version, slot_params = seb._param_slots[0]
+    slot_before = _leaves(slot_params)
+
+    traj = _make_traj(seb, 6, 3, 0)
+    rstate = seb._replay.insert(seb._replay.init(traj), traj)
+    update, core = seb._build_offpolicy_update(traj)
+    key = jax.random.key(1)
+    macc = seb._fresh_macc(jax.eval_shape(
+        core, params0, opt0, rstate, traj, key, jnp.int32(0)
+    )[3])
+    ring = jax.tree.leaves(rstate)
+    update(params0, opt0, rstate, _make_traj(seb, 6, 3, 1), macc, key,
+           jnp.int32(0))
+    assert all(leaf.is_deleted() for leaf in ring), "the ring is donated"
+    _assert_live_and_equal(slot_params, slot_before)
+
+
+def test_shared_device_learner_waits_for_each_update(monkeypatch):
+    """On a shared device an update queued behind another would hold a
+    fresh params set of its own, so after each publish the learner waits
+    for the update it dispatched, on the params it published."""
+    seb = _make_seb(batch=4, traj_len=2)
+    assert seb._shared_devices, "CPU test topology shares the device"
+    outputs, waited = [], []
+    get_update = seb._get_update
+
+    def recording_get_update(traj):
+        update, core = get_update(traj)
+
+        def run(*args):
+            out = update(*args)
+            outputs.append(out[0])
+            return out
+
+        return run, core
+
+    block = jax.block_until_ready
+    monkeypatch.setattr(seb, "_get_update", recording_get_update)
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda tree: waited.append(tree) or block(tree))
+    out = seb.run(jax.random.key(0), (4,), total_frames=64)
+    assert len(waited) == len(outputs) == out["updates"] > 0
+    assert all(w is p for w, p in zip(waited, outputs))
 
 
 # ------------------------------------------- actor-side queue put (retry)

@@ -30,7 +30,8 @@ SPANS = (
     "sebulba.learner.checkpoint",
     "sebulba.learner.log",
 )
-COUNTERS = ("updates", "param_version", "policy_lag_mean", "policy_lag_max")
+COUNTERS = ("updates", "param_version", "policy_lag_mean", "policy_lag_max",
+            "publishes_sent", "publishes_aliased")
 
 
 def tiny_sebulba(**config):
@@ -105,7 +106,9 @@ def traced_fit(work_dir: str, *, updates: int = 8, **kw) -> dict:
     }
 
 
-def check_summary(summary: dict) -> None:
+def check_summary(summary: dict, shared: bool) -> None:
+    """``shared``: the actor core is also a learner core, so every publish
+    sent aliases the update's output; otherwise none does."""
     spans, result = summary["spans"], summary["result"]
     names = [name for _, _, _, name in spans]
     for span in SPANS:
@@ -134,7 +137,11 @@ def check_summary(summary: dict) -> None:
     assert max(lags) == result["policy_lag_max"]
     assert sum(lags) / len(lags) == result["policy_lag_mean"]
     for p in by_name["sebulba.learner.publish"]:
-        assert {"version", "sent", "skipped"} <= set(p), p
+        assert {"version", "sent", "skipped", "aliased"} <= set(p), p
+        assert p["aliased"] == (p["sent"] if shared else 0), p
+    assert result["publishes_aliased"] == (
+        result["publishes_sent"] if shared else 0
+    )
     assert any("policy_lag=" in line for line in summary["log"])
 
 
@@ -150,7 +157,7 @@ def test_spans_and_lag_on_one_device(tmp_path):
     summary = traced_fit(str(tmp_path), num_actor_cores=1,
                          threads_per_actor_core=1, actor_batch_size=4,
                          queue_capacity=1, publish_throttle=False)
-    check_summary(summary)
+    check_summary(summary, shared=True)
     assert 0 <= summary["result"]["policy_lag_max"] <= 1 + 2
 
 
@@ -168,7 +175,7 @@ def test_spans_on_four_devices(tmp_path):
     assert proc.returncode == 0, proc.stderr[-3000:]
     summary = json.loads(out.read_text())
     assert summary["devices"] == 4
-    check_summary(summary)
+    check_summary(summary, shared=False)
 
 
 def test_program_names():
