@@ -1,11 +1,12 @@
-"""The IMPALA "deep" network (Espeholt et al. 2018, Fig. 3) on 84x84
-frames: the weights the benchmark makes, the plain float32 reference of
-its forward pass and V-trace loss, and its operations per frame, from its
-shapes.
+"""The IMPALA "deep" network (Espeholt et al. 2018, Fig. 3) on stacks of
+84x84 frames: the program's parts built from the configuration, the
+weights the benchmark makes, the plain float32 reference of its forward
+pass and V-trace loss, and its operations per frame, from its shapes.
 
 Three stages of (3x3 conv, 3x3/2 max-pool, two residual blocks of two 3x3
 convs), a ReLU'd dense layer, and linear policy and value heads.  The
-reference imports nothing of the program.
+reference imports nothing of the program: only ``program_parts`` does,
+inside its body.
 """
 
 from __future__ import annotations
@@ -17,8 +18,36 @@ import jax
 import jax.numpy as jnp
 
 from bench import reference as ref
+from bench.frame_stack import FrameStack
 
 F32 = jnp.float32
+
+
+# ---------------------------------------------------------------- program
+
+
+def program_parts(cfg: dict, traffic: dict):
+    """The system under test for this configuration: the conv
+    actor-critic on the device Pong, whose frames are stacked on the
+    channel axis up to ``frame_channels`` -> (``Sebulba`` keyword
+    arguments, loss settings for ``SebulbaConfig``)."""
+    from repro.agents.impala import ConvActorCritic
+    from repro.envs import Pong
+
+    pong = Pong(height=cfg["frame_height"], width=cfg["frame_width"])
+    env = FrameStack(pong, cfg["frame_channels"] // pong.obs_shape[-1])
+    if env.num_actions != cfg["num_actions"] or env.obs_shape[-1] != cfg["frame_channels"]:
+        raise ValueError("the configuration's actions and frames do not match the env")
+    net = ConvActorCritic(cfg["num_actions"], channels=tuple(cfg["channels"]),
+                          blocks=cfg["blocks_per_stage"], hidden=cfg["hidden"])
+    hp = cfg["loss"]
+    return {"network": net, "device_env": env}, {
+        "entropy_cost": hp["entropy_cost"], "value_cost": hp["value_cost"],
+        "discount": hp["discount"],
+    }
+
+
+# ---------------------------------------------------------------- weights
 
 
 def _stages(cfg: dict):

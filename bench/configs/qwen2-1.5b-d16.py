@@ -1,12 +1,14 @@
-"""qwen2-1.5b at 16 of its 28 layers: the weights the benchmark makes, the
-plain float32 reference of its forward pass and RL loss, and the
-operations and bytes its steps need, from its shapes.
+"""qwen2-1.5b at 16 of its 28 layers: the program's parts built from the
+configuration, the weights the benchmark makes, the plain float32
+reference of its forward pass and RL loss, and the operations and bytes
+its steps need, from its shapes.
 
 The reference follows the published Qwen2 decoder (arXiv:2407.10671):
 RMSNorm, grouped-query attention with QKV bias and rotate-half RoPE,
 SwiGLU MLP, tied input and output embeddings.  Its one addition is the
 RL value head (d_model -> 1 on the final normed state), which the policy
-agent adds to the model.  It imports nothing of the program.
+agent adds to the model.  It imports nothing of the program: only
+``program_parts`` does, inside its body.
 """
 
 from __future__ import annotations
@@ -29,6 +31,43 @@ def dims(cfg: dict) -> dict:
         "h": cfg.get("head_dim", d // H), "F": cfg["intermediate_size"],
         "V": cfg["vocab_size"], "L": cfg["num_hidden_layers"],
     }
+
+
+# ---------------------------------------------------------------- program
+
+
+def program_parts(cfg: dict, traffic: dict):
+    """The system under test for this configuration and traffic: the
+    LM policy agent (a dense Qwen2 decoder with a value head) and the
+    token-copy device env -> (``Sebulba`` keyword arguments, loss
+    settings for ``SebulbaConfig``)."""
+    from repro.agents.lm_policy import LMPolicyAgent
+    from repro.configs.base import ArchConfig
+    from repro.envs import TokenEnv
+    from repro.launch.steps import TrainHParams
+
+    arch = ArchConfig(
+        name=cfg["name"], family="dense", source=cfg["source"],
+        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
+        num_heads=cfg["num_attention_heads"],
+        num_kv_heads=cfg["num_key_value_heads"],
+        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
+        head_dim=cfg["head_dim"], qkv_bias=cfg["qkv_bias"],
+        rope_theta=cfg["rope_theta"], rms_norm_eps=cfg["rms_norm_eps"],
+        tie_embeddings=cfg["tie_word_embeddings"],
+    )
+    env = TokenEnv(vocab_size=arch.vocab_size,
+                   prompt_len=traffic["prompt_len"],
+                   data_vocab=traffic["data_vocab"])
+    if traffic["trajectory_length"] != env.episode_len:
+        raise ValueError("LM cells train on whole episodes: "
+                         "trajectory_length must be 2 * prompt_len")
+    hp = cfg["loss"]
+    agent = LMPolicyAgent(arch, max_seq=env.episode_len, hparams=TrainHParams(
+        rl_weight=hp["rl_weight"], entropy_cost=hp["entropy_cost"],
+        value_cost=hp["value_cost"], aux_weight=hp["aux_weight"],
+    ))
+    return {"agent": agent, "device_env": env}, {}
 
 
 # ---------------------------------------------------------------- weights

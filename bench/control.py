@@ -6,16 +6,17 @@ the chip, never by a benchmark run:
 
 For every seed, in one process: build the cell's program from the seed,
 let it make its first updates through the timed path, stop it, free it,
-and read the four compared numbers of the program against the reference
-(the lower readings).  For the first ``--faults`` seeds, also read them
-for the control (the reference computed at float8, put in the program's
+and read the compared numbers of the program against the reference (the
+lower readings).  For the first ``--faults`` seeds, also read them for
+the control (the reference computed at float8, put in the program's
 place) and for each fault the cell can have, planted in the reference put
 in the program's place: half of the batch left out, the exchange between
 learner chips left out (cells with several learners), a token altered
-after its log-prob was recorded, the state left unchanged (the upper
-readings).  Writes one JSON line per seed and, at the end, the summary:
-for each number the largest lower reading, the smallest reading of the
-control and of each fault, and a limit set between them.
+after its log-prob was recorded, the state left unchanged, every update's
+sign flipped (the upper readings).  Writes one JSON line per seed and, at
+the end, the summary: for each number the largest lower reading, the
+smallest reading of the control and of each fault, and a limit set
+between them.
 """
 
 import argparse
@@ -33,7 +34,7 @@ os.environ.setdefault("TPU_LOG_DIR", "disabled")
 from bench import common  # noqa: E402
 
 NUMBERS = ("logp_gap", "loss_gap", "grad_gap", "change_gap",
-           "grad_gap_median", "change_gap_median")
+           "grad_gap_median", "change_gap_median", "signed_change_gap_median")
 
 
 def seeds_arg(text: str) -> list[int]:
@@ -45,7 +46,7 @@ def seeds_arg(text: str) -> list[int]:
 
 
 def fault_variants(cell) -> list[str]:
-    v = ["control", "half_batch", "altered_token", "unchanged"]
+    v = ["control", "half_batch", "altered_token", "unchanged", "sign_flip"]
     if cell.chips > 1:
         v.insert(2, "no_exchange")
     return v

@@ -23,8 +23,8 @@ first gradient as the optimizer took it (from its state after one step)
 and the parameters after three updates (as the fourth receives them).
 Once the window has closed and the program's state is freed, the
 configuration's float32 reference follows the same three updates from
-the same seeded weights on the same trajectories, and four numbers are
-compared, each with a limit from the cell's file:
+the same seeded weights on the same trajectories.  These numbers are
+read, and a cell compares those its workload file gives a limit:
 
   logp_gap    widest |behaviour log-prob - reference log-prob| over the
               first trajectory (acted on with the initial weights):
@@ -33,8 +33,16 @@ compared, each with a limit from the cell's file:
               own units (a loss can lie near zero, so no relative gap);
   grad_gap    worst leaf's gap of first-gradient norms;
   change_gap  worst leaf's gap of the norms of the parameters' change
-              after three updates, over leaves whose reference gradient
-              is above a thousandth of the median leaf's.
+              after three updates, over the moved leaves: those whose
+              reference gradient is above a thousandth of the median
+              leaf's;
+  grad_gap_median, change_gap_median
+              the median leaf's gap of the same norms;
+  signed_change_gap_median
+              the median over moved leaves of |dp - dr| / |dr|, dp and dr
+              the program's and the reference's change after three
+              updates, element by element: the one number that sees the
+              sign and direction of an update, not only its size.
 """
 
 from __future__ import annotations
@@ -68,58 +76,11 @@ def make_optimizer(spec: dict):
         return optim.adam(spec["lr"], spec["b1"], spec["b2"], spec["eps"],
                           clip_norm=spec["clip_norm"])
     if spec["name"] == "rmsprop":
+        if spec.get("momentum", 0.0):
+            raise ValueError("the program's RMSProp has no momentum")
         return optim.rmsprop(spec["lr"], spec["decay"], spec["eps"],
                              clip_norm=spec["clip_norm"])
     raise ValueError(f"unknown optimizer {spec['name']!r}")
-
-
-def _lm_agent(cfg: dict, traffic: dict):
-    from repro.agents.lm_policy import LMPolicyAgent
-    from repro.configs.base import ArchConfig
-    from repro.envs import TokenEnv
-    from repro.launch.steps import TrainHParams
-
-    arch = ArchConfig(
-        name=cfg["name"], family="dense", source=cfg["source"],
-        num_layers=cfg["num_hidden_layers"], d_model=cfg["hidden_size"],
-        num_heads=cfg["num_attention_heads"],
-        num_kv_heads=cfg["num_key_value_heads"],
-        d_ff=cfg["intermediate_size"], vocab_size=cfg["vocab_size"],
-        head_dim=cfg["head_dim"], qkv_bias=cfg["qkv_bias"],
-        rope_theta=cfg["rope_theta"], rms_norm_eps=cfg["rms_norm_eps"],
-        tie_embeddings=cfg["tie_word_embeddings"],
-    )
-    env = TokenEnv(vocab_size=arch.vocab_size,
-                   prompt_len=traffic["prompt_len"],
-                   data_vocab=traffic["data_vocab"])
-    if traffic["trajectory_length"] != env.episode_len:
-        raise ValueError("LM cells train on whole episodes: "
-                         "trajectory_length must be 2 * prompt_len")
-    hp = cfg["loss"]
-    agent = LMPolicyAgent(arch, max_seq=env.episode_len, hparams=TrainHParams(
-        rl_weight=hp["rl_weight"], entropy_cost=hp["entropy_cost"],
-        value_cost=hp["value_cost"], aux_weight=hp["aux_weight"],
-    ))
-    return {"agent": agent, "device_env": env}, {}
-
-
-def _impala_agent(cfg: dict, traffic: dict):
-    from repro.agents.impala import ConvActorCritic
-    from repro.envs import Pong
-
-    env = Pong(height=cfg["frame_height"], width=cfg["frame_width"])
-    if env.num_actions != cfg["num_actions"] or env.obs_shape[-1] != cfg["frame_channels"]:
-        raise ValueError("the configuration's actions and frames do not match the env")
-    net = ConvActorCritic(cfg["num_actions"], channels=tuple(cfg["channels"]),
-                          blocks=cfg["blocks_per_stage"], hidden=cfg["hidden"])
-    hp = cfg["loss"]
-    return {"network": net, "device_env": env}, {
-        "entropy_cost": hp["entropy_cost"], "value_cost": hp["value_cost"],
-        "discount": hp["discount"],
-    }
-
-
-AGENTS = {"lm_policy": _lm_agent, "impala_conv": _impala_agent}
 
 
 _JITS: dict = {}
@@ -145,7 +106,7 @@ def build(cell, seed: int, devices):
     from repro.core.sebulba import Sebulba, SebulbaConfig
 
     cfg, traffic = cell.cfg, cell.traffic
-    parts, loss_kw = AGENTS[cfg["agent"]](cfg, traffic)
+    parts, loss_kw = cell.cfg_module.program_parts(cfg, traffic)
     scfg = SebulbaConfig(
         num_actor_cores=traffic["num_actor_cores"],
         threads_per_actor_core=traffic["threads_per_actor_core"],
@@ -402,10 +363,12 @@ class Follower:
     stated storage dtypes and float32 arithmetic.  The reference keeps the
     weights in its own layout (the configuration's ``to_reference``) and
     reports per-leaf norms under the program's leaf names (its
-    ``leaf_sumsq``)."""
+    ``leaf_sumsq``).  ``update_sign`` -1 negates each gradient before the
+    optimizer takes it, which flips every update of RMSProp and Adam (a
+    fault, never the reference)."""
 
     def __init__(self, cell, num: ref.Numerics = ref.HIGHEST,
-                 loss_rows=None, grad_rows=None):
+                 loss_rows=None, grad_rows=None, update_sign: float = 1.0):
         cfg, mod = cell.cfg, cell.cfg_module
         self.spec = cfg["optimizer"]
         to_ref = getattr(mod, "to_reference", lambda p: p)
@@ -421,19 +384,20 @@ class Follower:
                     p32, _rows(traj, grad_rows))
                 if loss_rows != grad_rows:
                     l, logp = loss_fn(p32, _rows(traj, loss_rows))
-            return l, logp, g
+            return l, logp, jax.tree.map(lambda x: update_sign * x, g)
 
-        def change(p3, p0):
+        def diff(a, b):
             return sumsq(jax.tree.map(
                 lambda x, y: x.astype(jnp.float32) - y.astype(jnp.float32),
-                p3, to_ref(p0)))
+                a, b))
 
         self._to_ref = jax.jit(to_ref, donate_argnums=0)
         self._step = jax.jit(step)
         self._opt = jax.jit(functools.partial(ref.opt_step, self.spec),
                             donate_argnums=(0, 1, 2))
         self._sumsq = jax.jit(sumsq)
-        self._change = jax.jit(change)
+        self._diff = jax.jit(diff)
+        self._change = jax.jit(lambda a, p: diff(a, to_ref(p)))
 
     def follow(self, p0, trajs: list[dict]) -> dict:
         """The updates from ``p0`` (donated) -> the losses, the first
@@ -453,10 +417,15 @@ class Follower:
             del g
         return {"losses": losses, "g1": g1, "logp1": logp1, "p3": p}
 
-    def change_norms(self, p3, p0) -> dict:
-        """Per-leaf norms of p3 (the reference's layout) - p0 (the
+    def change_norms(self, a, p) -> dict:
+        """Per-leaf norms of a (the reference's layout) - p (the
         program's), under the program's leaf names."""
-        return {k: math.sqrt(v) for k, v in _floats(self._change(p3, p0)).items()}
+        return {k: math.sqrt(v) for k, v in _floats(self._change(a, p)).items()}
+
+    def diff_norms(self, a, b) -> dict:
+        """Per-leaf norms of a - b, both in the reference's layout, under
+        the program's leaf names."""
+        return {k: math.sqrt(v) for k, v in _floats(self._diff(a, b)).items()}
 
 
 @jax.jit
@@ -472,7 +441,8 @@ def change_norms(p3, p0) -> dict:
 
 def compare(got: dict, want: dict) -> dict:
     """The readings of one side (``got``) against the reference.  A cell
-    compares those its workload file gives a limit."""
+    compares those its workload file gives a limit.  ``got["signed"]``
+    holds the per-leaf norms of its change less the reference's."""
     got_logp = np.asarray(got["logp"], np.float64)
     want_logp = np.asarray(want["logp"], np.float64)[: got_logp.shape[0]]
     moved = ref.moved_leaves(want["g1"])
@@ -484,6 +454,8 @@ def compare(got: dict, want: dict) -> dict:
         "change_gap": ref.worst_leaf_gap(got["dp"], want["dp"], moved)[0],
         "grad_gap_median": ref.median_leaf_gap(got["g1"], want["g1"]),
         "change_gap_median": ref.median_leaf_gap(got["dp"], want["dp"], moved),
+        "signed_change_gap_median": ref.median_leaf_ratio(
+            got["signed"], want["dp"], moved),
     }
 
 
@@ -491,13 +463,20 @@ def _learners(cell) -> int:
     return cell.chips - cell.traffic["num_actor_cores"] if cell.chips > 1 else 1
 
 
-def _reference_side(cell, seed, trajs, **kw) -> dict:
+def _follower(cell, **kw) -> Follower:
     key = (cell, "follower", tuple(sorted((k, str(v)) for k, v in kw.items())))
-    f = _jit_once(key, lambda: Follower(cell, **kw))
+    return _jit_once(key, lambda: Follower(cell, **kw))
+
+
+def _reference_side(cell, seed, trajs, **kw) -> tuple[dict, object]:
+    """A reference's readings and its parameters after the last update
+    (in the reference's layout)."""
+    f = _follower(cell, **kw)
     r = f.follow(make_params(cell, seed), trajs)
-    r["dp"] = f.change_norms(r.pop("p3"), make_params(cell, seed))
+    p3 = r.pop("p3")
+    r["dp"] = f.change_norms(p3, make_params(cell, seed))
     r["logp"] = r.pop("logp1")
-    return r
+    return r, p3
 
 
 def reference_readings(cell, seed: int, probe: Probe,
@@ -506,17 +485,22 @@ def reference_readings(cell, seed: int, probe: Probe,
     place, against the reference.  Variants: "control" (the reference
     computed at float8), "half_batch" (loss and update over half the
     rows), "no_exchange" (the update from the first learner's shard
-    alone), "altered_token" (the first trajectory's actions shifted by one
-    after their log-probs were recorded), "unchanged" (the state left as
-    it was).  Runs once the program's state is freed.  With ``detail``,
-    also the losses and per-leaf norms of every side, under "detail"."""
+    alone), "sign_flip" (every update with its sign flipped),
+    "altered_token" (the first trajectory's actions shifted by one after
+    their log-probs were recorded), "unchanged" (the state left as it
+    was).  Runs once the program's state is freed.  With ``detail``, also
+    the losses and per-leaf norms of every side, under "detail"."""
     B, trajs = cell.traffic["actor_batch_size"], probe.trajs
-    want = _reference_side(cell, seed, trajs)
+    f = _follower(cell)
+    want, want_p3 = _reference_side(cell, seed, trajs)
+    p3 = jax.device_put(probe.p3)
     program = {
         "losses": probe.program_losses(), "g1": probe.g1,
-        "dp": change_norms(jax.device_put(probe.p3), make_params(cell, seed)),
+        "dp": change_norms(p3, make_params(cell, seed)),
+        "signed": f.change_norms(want_p3, p3),
         "logp": trajs[0]["behaviour_logp"],
     }
+    del p3
     common.log(f"losses: program {program['losses']!r}, reference "
                f"{want['losses']!r}")
     kw = {
@@ -524,6 +508,7 @@ def reference_readings(cell, seed: int, probe: Probe,
         "half_batch": dict(loss_rows=slice(0, B // 2),
                            grad_rows=slice(0, B // 2)),
         "no_exchange": dict(grad_rows=slice(0, B // _learners(cell))),
+        "sign_flip": dict(update_sign=-1.0),
     }
     out, sides = {}, {"reference": want}
     for v in variants:
@@ -531,19 +516,22 @@ def reference_readings(cell, seed: int, probe: Probe,
         if v == "program":
             got = program
         elif v == "unchanged":
-            got = dict(program, dp={k: 0.0 for k in want["dp"]})
+            got = dict(program, dp={k: 0.0 for k in want["dp"]},
+                       signed=want["dp"])
         elif v == "altered_token":
             A = cell.cfg.get("num_actions") or cell.cfg["vocab_size"]
             alt = dict(trajs[0], actions=(trajs[0]["actions"] + 1) % A)
             got = program
-            w = dict(want, logp=_reference_side(cell, seed, [alt])["logp"])
+            w = dict(want, logp=_reference_side(cell, seed, [alt])[0]["logp"])
         else:
-            got = _reference_side(cell, seed, trajs, **kw[v])
+            got, got_p3 = _reference_side(cell, seed, trajs, **kw[v])
+            got["signed"] = f.diff_norms(got_p3, want_p3)
+            del got_p3
         out[v] = compare(got, w)
         sides[v] = got
     if detail:
         out["detail"] = {
-            k: {f: v[f] for f in ("losses", "g1", "dp")}
+            k: {n: v[n] for n in ("losses", "g1", "dp", "signed") if n in v}
             for k, v in sides.items()
         }
     return out

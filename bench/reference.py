@@ -189,10 +189,18 @@ def worst_leaf_gap(got: dict, want: dict, keep=None) -> tuple[float, str]:
 def median_leaf_gap(got: dict, want: dict, keep=None) -> float:
     """The median over leaves of the same per-leaf gap: steady from seed
     to seed where the worst leaf is one small leaf's rounding."""
+    return median_leaf_ratio({k: abs(got[k] - want[k]) for k in want},
+                             want, keep)
+
+
+def median_leaf_ratio(num: dict, want: dict, keep=None) -> float:
+    """The median over leaves of num / max(|want|, median |want|): with
+    ``num`` the norm of a leaf's difference from the reference (its
+    signed change less the reference's), the median leaf's distance from
+    the reference against the reference's own norm."""
     keys = [k for k in want if keep is None or k in keep]
     med = float(np.median([want[k] for k in keys]))
-    gaps = [abs(got[k] - want[k]) / max(want[k], med, 1e-30) for k in keys]
-    g = float(np.median(gaps))
+    g = float(np.median([num[k] / max(want[k], med, 1e-30) for k in keys]))
     return g if math.isfinite(g) else float("inf")
 
 

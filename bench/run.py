@@ -14,7 +14,9 @@ last line of standard output is one JSON object.
 Everything a cell needs is found by name from ``BENCHMARK.json``:
 ``bench/workloads/<cell>.json``, ``bench/configs/<config>.{json,py}``,
 ``bench/traffic/<traffic>.json``, ``bench/metrics/<metric>.py`` and
-``bench/drivers/<driver>.py``.
+``bench/drivers/<driver>.py``.  The configuration's module builds the
+program's parts (its ``program_parts``), so a new configuration, of any
+model family, is new files and no edit.
 """
 
 import time

@@ -50,38 +50,14 @@ TINY = {
 }
 
 
-# The four-chip IMPALA cell (1 actor : 3 learner chips) is not in
-# BENCHMARK.json: its limits need a number that tells half the batch and
-# one learner's shard from sound runs, read on four chips.  Its
-# configuration and traffic files are there; the tests describe the cell
-# here so that its reference and its faults over three learner devices
-# stay checked.
-X4 = "impala-deep-pong84-x4"
-X4_ENTRIES = {
-    "configs": {"name": "impala-deep-pong84",
-                "file": "bench/configs/impala-deep-pong84.json"},
-    "workloads": {"name": X4, "config": "impala-deep-pong84",
-                  "traffic": "pong84-b96-split1to3", "chips": 4},
-}
-X4_SPEC = {"name": X4, "driver": "sebulba", "warm_updates": 10,
-           "trace_seconds": 1}
-
-
 @pytest.fixture
 def tiny_cell():
     from bench import common
 
     def make(name, **spec):
         over = {k: dict(v) for k, v in TINY[name].items()}
-        bench = common.load_json(common.ROOT / "BENCHMARK.json")
-        if name == X4:
-            for key, entry in X4_ENTRIES.items():
-                bench[key].append(entry)
-            lm = common.load_json(
-                common.BENCH / "workloads" / "lmrl-qwen2-copy64.json")
-            over["spec"] = {**X4_SPEC, "limits": lm["limits"], **over["spec"]}
         over["spec"].update(spec)
-        return common.Cell(name, benchmark=bench, overrides=over)
+        return common.Cell(name, overrides=over)
 
     return make
 

@@ -13,7 +13,7 @@ from bench import common
 BENCH = json.loads((common.ROOT / "BENCHMARK.json").read_text())
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 NUMBERS = ("logp_gap", "loss_gap", "grad_gap", "change_gap",
-           "grad_gap_median", "change_gap_median")
+           "grad_gap_median", "change_gap_median", "signed_change_gap_median")
 WORKLOADS = sorted(p.stem for p in (common.BENCH / "workloads").glob("*.json"))
 
 
@@ -27,7 +27,8 @@ def test_cell_resolves(name):
     assert cell.spec["name"] == name
     assert cell.chips in (1, 4)
     assert callable(cell.driver.run)
-    for fn in ("make_params", "forward", "loss", "flops_per_frame"):
+    for fn in ("program_parts", "make_params", "forward", "loss",
+               "flops_per_frame"):
         assert callable(getattr(cell.cfg_module, fn)), fn
     assert set(cell.spec["limits"]) <= set(NUMBERS)
     assert "logp_gap" in cell.spec["limits"]
@@ -65,8 +66,8 @@ def test_benchmark_contract_shapes():
 
 
 def test_configs_keep_published_widths():
-    """The reduced keys are depth only; the shapes the reference and the
-    weights use come from the configuration file."""
+    """No reduced key is a width; the shapes the reference and the weights
+    use come from the configuration file."""
     for c in BENCH["configs"]:
         cfg = json.loads((common.ROOT / c["file"]).read_text())
         assert sorted(c["reduced"]) == sorted(cfg["reduced"])
@@ -75,17 +76,11 @@ def test_configs_keep_published_widths():
     qwen = common.Cell("lmrl-qwen2-copy64")
     n = qwen.cfg_module.param_count(qwen.cfg)
     assert math.isclose(n, 0.982e9, rel_tol=0.01), n
-    impala, cfg, _ = impala_files()
-    assert impala.param_count(cfg) == 1_089_828
-    assert impala.frame_flops(cfg) == pytest.approx(102.4e6, rel=0.01)
-
-
-def impala_files():
-    """The IMPALA configuration's module, its sizes and the four-chip
-    traffic, which the tests describe as a cell (conftest)."""
-    return (common.load_module(common.BENCH / "configs" / "impala-deep-pong84.py"),
-            common.load_json(common.BENCH / "configs" / "impala-deep-pong84.json"),
-            common.load_json(common.BENCH / "traffic" / "pong84-b96-split1to3.json"))
+    x4 = common.Cell("impala-deep-pong84-x4")
+    # 1,089,828 on one frame, and 3 x 3 x 3 x 16 more first-conv weights
+    # for the three further frames of the stack
+    assert x4.cfg_module.param_count(x4.cfg) == 1_089_828 + 432 == 1_090_260
+    assert x4.cfg_module.frame_flops(x4.cfg) == pytest.approx(108.5e6, rel=0.001)
 
 
 def conv_flops(hw, cin, cout):
@@ -94,18 +89,47 @@ def conv_flops(hw, cin, cout):
 
 def test_impala_act_step_cost():
     """The IMPALA act step's least work, against a hand count: the deep
-    network's convolutions (84 -> 42 -> 21 -> 11 after each pool, two
-    residual blocks of two convs a stage) and dense layers for each of the
-    96 frames, and the float32 weights plus one float32 frame batch."""
-    impala, cfg, traffic = impala_files()
-    frame = (conv_flops(84, 1, 16) + 4 * conv_flops(42, 16, 16)
+    network's convolutions on a stack of 4 frames (84 -> 42 -> 21 -> 11
+    after each pool, two residual blocks of two convs a stage) and dense
+    layers for each of the 96 frames, and the float32 weights plus one
+    float32 batch of stacked frames."""
+    x4 = common.Cell("impala-deep-pong84-x4")
+    frame = (conv_flops(84, 4, 16) + 4 * conv_flops(42, 16, 16)
              + conv_flops(42, 16, 32) + 4 * conv_flops(21, 32, 32)
              + conv_flops(21, 32, 32) + 4 * conv_flops(11, 32, 32)
              + 2 * (11 * 11 * 32 * 256 + 256 * 3 + 256 * 1))
-    flops, nbytes = impala.act_step_cost(cfg, traffic)
-    assert traffic["actor_batch_size"] == 96
+    flops, nbytes = x4.cfg_module.act_step_cost(x4.cfg, x4.traffic)
+    assert x4.traffic["actor_batch_size"] == 96
     assert flops == 96 * frame
-    assert nbytes == 4 * 1_089_828 + 96 * 84 * 84 * 4
+    # 4.36 MB of weights and 10.8 MB of frames
+    assert nbytes == 4 * 1_090_260 + 96 * 84 * 84 * 4 * 4
+
+
+def test_qwen2_builds_the_parents_arch_config():
+    """The Qwen2 module builds, field for field, the ArchConfig, env and
+    loss settings that the driver built before configurations built their
+    own parts, so both LM cells compile the programs they compiled."""
+    from repro.configs.base import ArchConfig
+    from repro.launch.steps import TrainHParams
+
+    for name in ("lmrl-qwen2-copy64", "lmrl-qwen2-learn"):
+        cell = common.Cell(name)
+        cfg, traffic = cell.cfg, cell.traffic
+        parts, loss_kw = cell.cfg_module.program_parts(cfg, traffic)
+        agent, env = parts["agent"], parts["device_env"]
+        assert set(parts) == {"agent", "device_env"} and loss_kw == {}
+        assert agent.cfg == ArchConfig(
+            name="qwen2-1.5b-d16", family="dense",
+            source="https://huggingface.co/Qwen/Qwen2-1.5B", num_layers=16,
+            d_model=1536, num_heads=12, num_kv_heads=2, d_ff=8960,
+            vocab_size=151936, head_dim=128, qkv_bias=True,
+            rope_theta=1000000.0, rms_norm_eps=1e-06, tie_embeddings=True,
+        )
+        assert agent.hp == TrainHParams(rl_weight=0.1, entropy_cost=0.003,
+                                        value_cost=0.5, aux_weight=0.01)
+        assert (env.num_actions, env.prompt_len, env.data_vocab, env.task) == (
+            151936, traffic["prompt_len"], 16, "copy")
+        assert agent.max_seq == env.episode_len == traffic["trajectory_length"]
 
 
 def test_cpu_run_is_refused():

@@ -15,18 +15,20 @@ import jax
 import numpy as np
 import pytest
 
+from bench import common
 from bench.drivers import sebulba as drv
 
 CELLS = ["lmrl-qwen2-copy64", "lmrl-qwen2-learn", "impala-deep-pong84-x4"]
+SIGNED = "signed_change_gap_median"
 
 
-def sound_readings(cell, devices, seed):
+def sound_readings(cell, devices, seed, variants=("control",)):
     s = drv.Session(cell, seed, devices)
     s.probe.wait_for(drv.CAPTURED + 1, s.alive)
     s.stop()
     probe = s.probe
     del s
-    return drv.reference_readings(cell, seed, probe, ["program", "control"])
+    return drv.reference_readings(cell, seed, probe, ["program", *variants])
 
 
 @pytest.fixture(scope="module")
@@ -54,6 +56,17 @@ def fault_unchanged(monkeypatch):
     from repro import optim
 
     monkeypatch.setattr(optim, "apply_updates", lambda params, updates: params)
+
+
+def fault_sign_flip(monkeypatch):
+    from repro import optim
+
+    def apply_updates(params, updates):
+        return jax.tree.map(
+            lambda p, u: (p.astype(np.float32) - u.astype(np.float32))
+            .astype(p.dtype), params, updates)
+
+    monkeypatch.setattr(optim, "apply_updates", apply_updates)
 
 
 def fault_half_batch(monkeypatch):
@@ -123,6 +136,7 @@ def fault_dropped(monkeypatch):
 
 FAULTS = {
     "unchanged": fault_unchanged,
+    "sign_flip": fault_sign_flip,
     "dropped": fault_dropped,
     "half_batch": fault_half_batch,
     "no_exchange": fault_no_exchange,
@@ -130,9 +144,17 @@ FAULTS = {
 }
 
 
-# the exchange between learner chips exists only in the four-chip cell
+def compares(name: str, number: str) -> bool:
+    spec = common.load_json(common.BENCH / "workloads" / f"{name}.json")
+    return number in spec["limits"]
+
+
+# the exchange between learner chips exists only in the four-chip cell; a
+# flipped sign changes no norm, so only a cell that compares the signed
+# change can see it
 CASES = [(name, fault) for name in CELLS for fault in sorted(FAULTS)
-         if fault != "no_exchange" or name.endswith("-x4")]
+         if (fault != "no_exchange" or name.endswith("-x4"))
+         and (fault != "sign_flip" or compares(name, SIGNED))]
 
 
 @pytest.mark.parametrize("name,fault", CASES)
@@ -150,4 +172,19 @@ def test_fault_is_not_correct(name, fault, tiny_cell, cpu_devices,
     assert result["correct"] is False, checks
     broken = [k for k, c in checks.items() if not c["ok"]]
     assert broken
+    if fault == "sign_flip":
+        assert SIGNED in broken, checks
     assert np.isfinite(result["metrics"]["train_frames_per_s"]["value"])
+
+
+def test_signed_change_sees_a_flipped_update(tiny_cell, cpu_devices):
+    """A sound run reads the signed change far under the cell's limit; the
+    same run with every update's sign flipped, in the reference put in the
+    program's place, reads about 2 (the change less its own negative),
+    above it, while the norms of its change match the reference's."""
+    cell = tiny_cell("impala-deep-pong84-x4")
+    r = sound_readings(cell, cpu_devices(cell), seed=17, variants=["sign_flip"])
+    limit = cell.spec["limits"][SIGNED]
+    assert r["program"][SIGNED] < limit / 10
+    assert r["sign_flip"][SIGNED] > 1.5 > limit
+    assert r["sign_flip"]["grad_gap"] < 1e-3

@@ -21,7 +21,7 @@ def f32(tree):
 
 
 def lm_program(cell):
-    parts, _ = drv.AGENTS["lm_policy"](cell.cfg, cell.traffic)
+    parts, _ = cell.cfg_module.program_parts(cell.cfg, cell.traffic)
     agent = parts["agent"]
     arch = dataclasses.replace(agent.cfg, param_dtype="float32")
     agent.cfg = arch
@@ -80,7 +80,7 @@ def test_qwen2_loss_matches_program(tiny_cell):
 
 
 def impala_program(cell, devices):
-    parts, loss_kw = drv.AGENTS["impala_conv"](cell.cfg, cell.traffic)
+    parts, loss_kw = cell.cfg_module.program_parts(cell.cfg, cell.traffic)
     from repro.core.sebulba import Sebulba, SebulbaConfig
 
     return Sebulba(
