@@ -5,9 +5,12 @@ Sebulba dataflow with **zero changes to core/sebulba.py**: autoregressive
 generation *is* the ``act()`` hot loop, and the decode state — KV cache
 plus position counter — *is* the declared recurrent carry.  Everything the
 runner already does for recurrent agents (thread the carry through the
-fused donated act-step, episode-reset it where ``discount == 0``, snapshot
-it into ``Trajectory.init_carry``, split it across learner shards, store
-it through replay) therefore applies to LM rollouts for free.  This is the
+fused donated act-step, episode-reset it where ``discount == 0``)
+therefore applies to LM rollouts for free.  The loss never reads
+``Trajectory.init_carry`` (trajectories are whole episodes from the zero
+carry), and the agents declare so (``AgentSpec.reads_init_carry=False``):
+the runner stores no snapshot of the decode state, which for a Mamba-2
+model is megabytes a row.  This is the
 RLAX architecture (PAPERS.md) expressed on our stack.
 
 Carry layout (the contract tests/test_lm_policy.py pins):
@@ -69,7 +72,7 @@ class LMPolicyAgent:
     ``launch.steps.TrainHParams`` (CE + rl_weight * V-trace + aux).
     """
 
-    spec = AgentSpec(recurrent=True)
+    spec = AgentSpec(recurrent=True, reads_init_carry=False)
 
     def __init__(self, cfg, *, max_seq: int, hparams: TrainHParams | None = None,
                  cache_dtype=None):
@@ -173,7 +176,7 @@ class LMReplayPolicyAgent(LMPolicyAgent):
     V-trace term per sequence), per-sequence TD priorities out — stale
     generations replay with both corrections RLAX prescribes."""
 
-    spec = AgentSpec(recurrent=True, replay=True)
+    spec = AgentSpec(recurrent=True, replay=True, reads_init_carry=False)
 
     def loss(self, params, traj, weights=None):
         total, metrics, out = self._objective(params, traj, weights)

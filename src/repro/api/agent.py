@@ -84,11 +84,18 @@ class AgentSpec:
                      (``()`` means no extras).  Gives agent extras (e.g.
                      MuZero visit distributions) a checked, named slot in
                      the trajectory ring instead of an anonymous pytree.
+    ``reads_init_carry`` — whether ``loss`` reads ``Trajectory.init_carry``,
+                     the carry entering the trajectory's first step.  A
+                     recurrent agent that declares False (the LM agents:
+                     every trajectory is a whole episode from the zero
+                     carry) gets no such snapshot: the runner keeps none
+                     in the actors' rings and queues ``()`` in its place.
     """
 
     recurrent: bool = False
     replay: bool = False
     extras_keys: tuple[str, ...] = ()
+    reads_init_carry: bool = True
 
     def __post_init__(self):
         keys = self.extras_keys

@@ -44,6 +44,12 @@ class ArchConfig:
     # A=(local) attn, X=cross-attn insert, S=self-attn, M=moe, D=dense-ff.
     layer_pattern: str = ""
     attn_logit_softcap: float = 0.0
+    # per-layer mixer of a dense stack, one entry a layer: "attention" or
+    # "mamba" (a Mamba-2 mixer in the attention's place, granite-4.0-h);
+    # () means attention in every layer
+    layer_types: tuple[str, ...] = ()
+    # softmax scale of attention; 0 -> 1/sqrt(head_dim)
+    attention_multiplier: float = 0.0
 
     # MoE --------------------------------------------------------------------
     num_experts: int = 0
@@ -70,9 +76,16 @@ class ArchConfig:
     num_audio_frames: int = 0  # audio: encoder frames
     encoder_layers: int = 0  # audio: encoder depth (decoder = num_layers)
 
-    # positions: "rope" (default) or "learned" (whisper)
+    # positions: "rope" (default), "learned" (whisper) or "none" (NoPE)
     pos_embed: str = "rope"
     max_position: int = 0  # learned pos table size; 0 -> unused
+
+    # granite scalars: the embedding is multiplied by embedding_multiplier,
+    # each residual branch by residual_multiplier, and the logits divided
+    # by logits_scaling (1.0 each -> none of them applies)
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     # training ---------------------------------------------------------------
     param_dtype: str = "bfloat16"
@@ -90,6 +103,18 @@ class ArchConfig:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         if self.family == "hybrid" and self.rnn_width == 0:
             object.__setattr__(self, "rnn_width", self.d_model)
+        if self.layer_types:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            if len(self.layer_types) != self.num_layers or not set(
+                self.layer_types
+            ) <= {"attention", "mamba"}:
+                raise ValueError(
+                    "layer_types names each layer's mixer, 'attention' or "
+                    f"'mamba', once a layer: {self.layer_types!r} for "
+                    f"{self.num_layers} layers"
+                )
+            if self.family != "dense":
+                raise ValueError("layer_types applies to the dense family")
 
     # -- derived -----------------------------------------------------------
 

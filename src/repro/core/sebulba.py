@@ -61,7 +61,9 @@ runtime.  Recurrent agents (R2D2, repro/agents/recurrent.py) get their
 carry threaded through the fused act-step (donated, reset on episode
 boundaries via the discount channel), the carry entering step 0 of each
 trajectory slice stored alongside it (``Trajectory.init_carry`` — the
-R2D2 "stored state", which rides the replay ring like any other leaf), and
+R2D2 "stored state", which rides the replay ring like any other leaf;
+agents whose loss never reads it declare ``reads_init_carry=False`` and
+get none), and
 a learner-side burn-in (``SebulbaConfig.burn_in``) that re-unrolls the
 first K steps gradient-free to refresh the stale stored state before the
 V-trace loss.  Feed-forward agents declare no capabilities and thread the
@@ -254,6 +256,9 @@ class Sebulba:
             agent, replay_hint=config.replay is not None
         )
         self._recurrent = self.spec.recurrent
+        # the carry entering each trajectory is snapshotted into the ring
+        # and queued with the trajectory only where the loss reads it
+        self._stores_carry = self._recurrent and self.spec.reads_init_carry
         self.opt = optimizer
         self.env_factory = env_factory
         self.make_batched_env = make_batched_env
@@ -366,11 +371,12 @@ class Sebulba:
         if config.burn_in < 0:
             raise ValueError("burn_in must be >= 0")
         if config.burn_in:
-            if not self._recurrent:
+            if not self._stores_carry:
                 raise ValueError(
                     "burn_in is a recurrent-agent feature (it refreshes the "
-                    "stored carry); feed-forward agents have no state to "
-                    "burn in"
+                    "stored carry); feed-forward agents, and agents that "
+                    "declare reads_init_carry=False, have no stored state "
+                    "to burn in"
                 )
             if config.burn_in >= config.trajectory_length:
                 raise ValueError(
@@ -543,7 +549,7 @@ class Sebulba:
         previous env step ended an episode, so those batch rows restart
         from the agent's initial state before acting.  The post-reset carry
         is what ``buffer_add`` snapshots at t == 0 — the R2D2 stored state
-        for the slice.
+        for the slice — where the agent's loss reads it.
 
         Every agent takes the canonical ``act(params, obs, rng, carry)``
         (repro.api); the reset branch keys on the DECLARED capability at
@@ -563,7 +569,8 @@ class Sebulba:
         actions, aux, new_carry = self.agent.act(params, obs, a_rng, carry)
         with jax.named_scope("ring_write"):
             buf = buffer_add(
-                buf, obs, actions, aux.logp, aux.extras, rew_disc, carry
+                buf, obs, actions, aux.logp, aux.extras, rew_disc,
+                carry if self._stores_carry else (),
             )
         return actions, buf, rng, new_carry
 
@@ -596,7 +603,7 @@ class Sebulba:
             api.validate_extras(aux_spec.extras, self.spec, self._agent_name)
         buf = device_buffer_init(
             self.cfg.trajectory_length, obs_spec, act_spec, aux_spec.logp,
-            aux_spec.extras, carry_spec,
+            aux_spec.extras, carry_spec if self._stores_carry else (),
         )
         return jax.device_put(buf, device)
 
@@ -730,7 +737,8 @@ class Sebulba:
         actions, aux, new_carry = self.agent.act(params, obs, a_rng, carry)
         with jax.named_scope("ring_write"):
             buf = buffer_add(
-                buf, obs, actions, aux.logp, aux.extras, rew_disc, carry
+                buf, obs, actions, aux.logp, aux.extras, rew_disc,
+                carry if self._stores_carry else (),
             )
         with jax.named_scope("env_step"):
             env_state, ts = self._fleet.step(env_state, actions)

@@ -8,7 +8,9 @@ the MXU instead of a length-T elementwise loop.
 Dispatch: on TPU backends the Pallas kernel (ssd_scan.py) is used; elsewhere
 (CPU dry-run, tests) the identical chunked algorithm runs as pure jnp.
 ``interpret=True`` forces the Pallas kernel in interpreter mode for kernel
-tests on CPU.
+tests on CPU.  The kernel has no backward of its own: its gradient is the
+jnp chunk scan's memory-bounded VJP (``_ssd_pallas``), which re-runs one
+chunk at a time, so a learner differentiates through the TPU forward.
 """
 
 from __future__ import annotations
@@ -49,7 +51,9 @@ def _chunked_ssd(
     scores = jnp.einsum("bcqn,bcsn->bcqs", Cf, Bf)  # (B,nc,Q,Q)
     delta = cum[:, :, :, None, :] - cum[:, :, None, :, :]  # (B,nc,Q(t),Q(s),H)
     causal = jnp.tril(jnp.ones((Q, Q), bool))
-    decay_m = jnp.where(causal[None, None, :, :, None], jnp.exp(delta), 0.0)
+    # masked before the exp: exp of the (positive) s > t entries overflows,
+    # and inf * 0 in the backward pass would be NaN
+    decay_m = jnp.exp(jnp.where(causal[None, None, :, :, None], delta, -jnp.inf))
     attn = scores[..., None] * decay_m  # (B,nc,Q,Q,H)
     dx = xf * dtf[..., None]  # (B,nc,Q,H,P)
     y_intra = jnp.einsum("bcqsh,bcshp->bcqhp", attn, dx)
@@ -92,7 +96,8 @@ def _ssd_one_chunk(S_prev, xc, dtc, Bc, Cc, A):
     scores = jnp.einsum("bqn,bsn->bqs", Cc, Bc)
     delta = cum[:, :, None, :] - cum[:, None, :, :]  # (B,Q,S,H)
     causal = jnp.tril(jnp.ones((Q, Q), bool))
-    decay = jnp.where(causal[None, :, :, None], jnp.exp(delta), 0.0)
+    # masked before the exp (see ``_chunked_ssd``): the VJP stays finite
+    decay = jnp.exp(jnp.where(causal[None, :, :, None], delta, -jnp.inf))
     attn = scores[..., None] * decay  # (B,Q,S,H)
     dx = xc * dtc[..., None]
     y_intra = jnp.einsum("bqsh,bshp->bqhp", attn, dx)
@@ -186,6 +191,42 @@ def _ssd_vjp_bwd(nc_static, res, cts):
 _ssd_chunk_scan.defvjp(_ssd_vjp_fwd, _ssd_vjp_bwd)
 
 
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _ssd_pallas(x, dt, A, Bm, Cm, nc, interpret):
+    from repro.kernels.ssd_scan.ssd_scan import ssd_scan_pallas
+
+    return ssd_scan_pallas(
+        x, dt, A, Bm, Cm, chunk=x.shape[1] // nc, interpret=interpret
+    )
+
+
+def _ssd_pallas_fwd(x, dt, A, Bm, Cm, nc, interpret):
+    return _ssd_pallas(x, dt, A, Bm, Cm, nc, interpret), (x, dt, A, Bm, Cm)
+
+
+def _ssd_pallas_bwd(nc, interpret, res, cts):
+    """The Pallas forward's gradient is the jnp chunk-scan VJP
+    (``_ssd_vjp_bwd``), fed the state entering each chunk: zero for the
+    first chunk, and for later ones recomputed here by the jnp chunk scan,
+    so that the kernel writes no per-chunk states it does not need."""
+    x, dt, A, Bm, Cm = res
+    Bsz, _, H, P = x.shape
+    if nc == 1:
+        S_prevs = jnp.zeros((1, Bsz, H, P, Bm.shape[-1]), jnp.float32)
+    else:
+        S_prevs = _ssd_chunk_scan_fwd_impl(x, dt, A, Bm, Cm, nc)[2]
+    return _ssd_vjp_bwd(nc, (x, dt, A, Bm, Cm, S_prevs), cts)
+
+
+_ssd_pallas.defvjp(_ssd_pallas_fwd, _ssd_pallas_bwd)
+
+
+def _pad_time(a: jax.Array, T_pad: int) -> jax.Array:
+    pad = [(0, 0)] * a.ndim
+    pad[1] = (0, T_pad - a.shape[1])
+    return jnp.pad(a, pad)
+
+
 @functools.partial(jax.jit, static_argnames=("chunk", "impl", "interpret"))
 def ssd_scan(
     x: jax.Array,
@@ -199,22 +240,32 @@ def ssd_scan(
     impl: str = "auto",
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
-    """Chunked SSD scan.  See ref.py for shapes."""
+    """Chunked SSD scan.  See ref.py for shapes.
+
+    Any length T: a sequence that is not a whole number of chunks is
+    padded at its end with zero steps (dt = 0: no decay, no input), which
+    leaves every earlier output and the final state exactly as they were,
+    since the scan is causal; the padded outputs are dropped.  A sequence
+    shorter than a chunk is one chunk of its own length rounded up to 8
+    rows.  The Pallas forward (the TPU's) differentiates through the jnp
+    chunk scan's VJP.
+    """
     if impl == "auto":
         impl = "pallas" if jax.default_backend() == "tpu" else "jnp"
+    T = x.shape[1]
+    Q = min(chunk, -(-T // 8) * 8)
+    nc = -(-T // Q)
+    if nc * Q != T:
+        x, dt, Bm, Cm = (_pad_time(a, nc * Q) for a in (x, dt, Bm, Cm))
     if impl == "pallas" or interpret:
-        from repro.kernels.ssd_scan.ssd_scan import ssd_scan_pallas
-
-        return ssd_scan_pallas(
-            x, dt, A, Bm, Cm, init_state, chunk=chunk, interpret=interpret
-        )
-    if impl == "jnp" and init_state is None:
+        if init_state is not None:
+            raise NotImplementedError("kernel path starts from zero state")
+        y, S = _ssd_pallas(x, dt, A, Bm, Cm, nc, interpret)
+    elif impl == "jnp" and init_state is None:
         # chunk-scan layout with the memory-bounded custom VJP (§Perf):
         # backward re-runs one chunk at a time instead of saving every
         # chunk's (B, Q, Q, H) attn/decay tensors.
-        T = x.shape[1]
-        Q = min(chunk, T)
-        if T % Q:
-            raise ValueError(f"seq len {T} not divisible by chunk {Q}")
-        return _ssd_chunk_scan(x, dt, A, Bm, Cm, T // Q)
-    return _chunked_ssd(x, dt, A, Bm, Cm, chunk, init_state)
+        y, S = _ssd_chunk_scan(x, dt, A, Bm, Cm, nc)
+    else:
+        y, S = _chunked_ssd(x, dt, A, Bm, Cm, Q, init_state)
+    return y[:, :T], S

@@ -84,7 +84,7 @@ def mamba2_block(params, x: jax.Array, cfg: ArchConfig) -> jax.Array:
     dt = jax.nn.softplus(dt.astype(jnp.float32) + params["dt_bias"])  # (B,T,H)
     A = -jnp.exp(params["A_log"])  # (H,) negative decay
     xh = xc.reshape(Bsz, T, H, P)
-    y, _ = ssd_scan(xh, dt, A, Bm, Cm, chunk=min(cfg.ssm_chunk, T))
+    y, _ = ssd_scan(xh, dt, A, Bm, Cm, chunk=cfg.ssm_chunk)
     y = y + params["D"].astype(y.dtype)[:, None] * xh
     y = y.reshape(Bsz, T, cfg.d_inner)
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)

@@ -61,6 +61,20 @@ def _kv_cache(cb: _CacheBuilder, batch, s, K, h, stacked_layers=0, seq_axis="kv_
     return {"k": k, "v": v}, {"k": ka, "v": va}
 
 
+def _ssm_cache(cb: _CacheBuilder, cfg: ArchConfig, batch):
+    """One Mamba-2 layer's batch-leading decode state: the float32 SSD
+    state and the conv window, in the cache dtype."""
+    ssm, sa = cb.zeros(
+        (batch, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+        ("batch", "ssm_heads", None, "ssm_state"), jnp.float32,
+    )
+    conv, ca = cb.zeros(
+        (batch, cfg.conv_width - 1, mamba2.conv_dim(cfg)),
+        ("batch", None, "ssm_inner"),
+    )
+    return {"ssm": ssm, "conv": conv}, {"ssm": sa, "conv": ca}
+
+
 class Model:
     def __init__(self, cfg: ArchConfig, moe_impl: str = "sort",
                  unroll: bool = False, mesh=None):
@@ -77,7 +91,8 @@ class Model:
         # sliding-window long-context variants — must use the looped path.
         self.stacked = not unroll and (
             cfg.family in ("ssm", "audio", "moe")
-            or (cfg.family == "dense" and uniform and self.kinds[0] == "G")
+            or (cfg.family == "dense" and uniform and self.kinds[0] == "G"
+                and not cfg.layer_types)
         )
 
     # ------------------------------------------------------------------ init
@@ -119,7 +134,10 @@ class Model:
         else:
             for i in range(cfg.num_layers):
                 with b.scope(f"layer_{i}"):
-                    tf.init_attn_layer(b, cfg)
+                    if self._is_mamba(i):
+                        mamba2.init_mamba2_block(b, "mixer", cfg)
+                    else:
+                        tf.init_attn_layer(b, cfg)
                     tf.init_ffn_layer(b, cfg, "dense")
 
     def _init_moe(self, b: ParamBuilder) -> None:
@@ -203,13 +221,36 @@ class Model:
         every = self.cfg.cross_attn_every
         return every > 0 and (i + 2) % every == 0
 
+    def _is_mamba(self, i: int) -> bool:
+        """Whether layer i of a dense stack mixes with Mamba-2 instead of
+        attention (``cfg.layer_types``)."""
+        return bool(self.cfg.layer_types) and self.cfg.layer_types[i] == "mamba"
+
     # --------------------------------------------------------------- forward
+
+    def _scale_embedding(self, x: jax.Array) -> jax.Array:
+        cfg = self.cfg
+        if "gemma" in cfg.name:
+            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+        return x
+
+    def _heads(self, params, x: jax.Array) -> tuple[jax.Array, jax.Array]:
+        """Final-normed hidden states -> (logits f32, values (B, T) f32);
+        the logits divided by ``cfg.logits_scaling`` where that is not 1."""
+        logits = layers.unembed(params["embedding"], x)
+        if self.cfg.logits_scaling != 1.0:
+            logits = logits / self.cfg.logits_scaling
+        values = jnp.einsum(
+            "btd,dk->btk", x, params["value_head"]["w"].astype(x.dtype)
+        )[..., 0].astype(jnp.float32)
+        return logits, values
 
     def _embed(self, params, tokens: jax.Array) -> jax.Array:
         cfg = self.cfg
         x = layers.embed(params["embedding"], tokens, jnp.dtype(cfg.param_dtype))
-        if "gemma" in cfg.name:
-            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        x = self._scale_embedding(x)
         if cfg.pos_embed == "learned":
             pos = jnp.arange(tokens.shape[1])
             x = x + layers.learned_pos(params["pos"], pos).astype(x.dtype)
@@ -240,10 +281,7 @@ class Model:
             raise ValueError(cfg.family)
 
         x = layers.rms_norm(params["final_norm"], x, cfg.rms_norm_eps)
-        logits = layers.unembed(params["embedding"], x)
-        values = jnp.einsum(
-            "btd,dk->btk", x, params["value_head"]["w"].astype(x.dtype)
-        )[..., 0].astype(jnp.float32)
+        logits, values = self._heads(params, x)
         return logits, values, aux
 
     def _apply_layers(self, params, x, body, remat, layer_ids=None):
@@ -280,17 +318,34 @@ class Model:
                 h = tf.attn_sublayer(p, h, positions, cfg)
                 return tf.ffn_sublayer(p, h, cfg, self.moe_impl, self.mesh)
             return tf.scan_layers(params["blocks"], x, body, remat=remat)
-        # python loop: heterogeneous dense (gemma3) or unrolled layouts
+        # python loop: heterogeneous dense (gemma3), mixed attention and
+        # Mamba-2 layers (granite-4.0-h), or unrolled layouts
         for i, kind in enumerate(self.kinds):
             window, theta = tf.local_params(cfg, kind)
             p = params[f"layer_{i}"]
-            def one(p, h, window=window, theta=theta):
-                h = tf.attn_sublayer(p, h, positions, cfg, window=window, theta=theta)
+            def one(p, h, window=window, theta=theta, i=i):
+                h = self._mixer(i, p, h, positions, window, theta)
                 return tf.ffn_sublayer(p, h, cfg, self.moe_impl, self.mesh)
             f = jax.checkpoint(one) if remat else one
             x, a = f(p, x)
             aux += a
         return x, aux
+
+    def _mixer(self, i, p, x, positions, window, theta):
+        """Layer i's mixer with its residual: attention, or Mamba-2 where
+        ``cfg.layer_types`` says so (each under a named scope of its kind
+        in mixed stacks, so that a profile tells them apart)."""
+        cfg = self.cfg
+        if not cfg.layer_types:
+            return tf.attn_sublayer(p, x, positions, cfg, window=window,
+                                    theta=theta)
+        if self._is_mamba(i):
+            with jax.named_scope("mamba2"):
+                return tf.residual(x, mamba2.mamba2_block(p["mixer"], x, cfg),
+                                   cfg)
+        with jax.named_scope("attention"):
+            return tf.attn_sublayer(p, x, positions, cfg, window=window,
+                                    theta=theta)
 
     def _fwd_hybrid(self, params, x, positions, remat):
         cfg = self.cfg
@@ -387,9 +442,12 @@ class Model:
         if cfg.family in ("dense", "moe"):  # looped: gemma3 or unrolled
             cache, cache_axes = {}, {}
             for i, kind in enumerate(self.kinds):
-                window, _ = tf.local_params(cfg, kind)
-                s = min(window, seq_len) if window else seq_len
-                c, a = _kv_cache(cb, batch, s, K, h)
+                if self._is_mamba(i):
+                    c, a = _ssm_cache(cb, cfg, batch)
+                else:
+                    window, _ = tf.local_params(cfg, kind)
+                    s = min(window, seq_len) if window else seq_len
+                    c, a = _kv_cache(cb, batch, s, K, h)
                 cache[f"layer_{i}"], cache_axes[f"layer_{i}"] = c, a
             return cache, cache_axes
 
@@ -410,16 +468,8 @@ class Model:
                 }
             cache, cache_axes = {}, {}
             for i in range(L):
-                ssm, sa = cb.zeros(
-                    (batch, H, P, N),
-                    ("batch", "ssm_heads", None, "ssm_state"), jnp.float32,
-                )
-                conv, ca = cb.zeros(
-                    (batch, cfg.conv_width - 1, mamba2.conv_dim(cfg)),
-                    ("batch", None, "ssm_inner"),
-                )
-                cache[f"layer_{i}"] = {"ssm": ssm, "conv": conv}
-                cache_axes[f"layer_{i}"] = {"ssm": sa, "conv": ca}
+                cache[f"layer_{i}"], cache_axes[f"layer_{i}"] = _ssm_cache(
+                    cb, cfg, batch)
             return cache, cache_axes
 
         if cfg.family == "hybrid":
@@ -497,9 +547,10 @@ class Model:
         ``block_tables`` through.  dense/moe only (the families whose
         decode path is pure global attention)."""
         cfg = self.cfg
-        if cfg.family not in ("dense", "moe"):
+        if cfg.family not in ("dense", "moe") or "mamba" in cfg.layer_types:
             raise ValueError(
-                f"paged cache supports dense/moe only, not {cfg.family}"
+                f"paged cache supports attention-only dense/moe stacks, not "
+                f"{cfg.name}"
             )
         for kind in self.kinds:
             if tf.local_params(cfg, kind)[0]:
@@ -563,6 +614,8 @@ class Model:
         with jax.named_scope("unembed"):
             x = layers.rms_norm(params["final_norm"], x, cfg.rms_norm_eps)
             logits = layers.unembed(params["embedding"], x)
+            if cfg.logits_scaling != 1.0:
+                logits = logits / cfg.logits_scaling
         values = jnp.einsum(
             "btd,dk->btk", x, params["value_head"]["w"].astype(x.dtype)
         )[..., 0].astype(jnp.float32)
@@ -598,11 +651,24 @@ class Model:
         elif cfg.family in ("dense", "moe"):
             for i, kind in enumerate(self.kinds):
                 window, theta = tf.local_params(cfg, kind)
-                x, c2 = tf.attn_sublayer_decode(
-                    params[f"layer_{i}"], cache[f"layer_{i}"], x, pos, cfg,
-                    window=window, theta=theta, block_tables=block_tables,
-                )
-                x, _ = tf.ffn_sublayer(params[f"layer_{i}"], x, cfg, self.moe_impl, self.mesh)
+                p, c = params[f"layer_{i}"], cache[f"layer_{i}"]
+                if not cfg.layer_types:
+                    x, c2 = tf.attn_sublayer_decode(
+                        p, c, x, pos, cfg, window=window, theta=theta,
+                        block_tables=block_tables,
+                    )
+                elif self._is_mamba(i):
+                    with jax.named_scope("mamba2"):
+                        out, c2 = mamba2.mamba2_decode_step(
+                            p["mixer"], c, x, cfg)
+                        x = tf.residual(x, out, cfg)
+                else:
+                    with jax.named_scope("attention"):
+                        x, c2 = tf.attn_sublayer_decode(
+                            p, c, x, pos, cfg, window=window, theta=theta,
+                            block_tables=block_tables,
+                        )
+                x, _ = tf.ffn_sublayer(p, x, cfg, self.moe_impl, self.mesh)
                 new_cache[f"layer_{i}"] = c2
         elif cfg.family == "ssm":
             def step(p, c, h):
@@ -676,16 +742,15 @@ class Model:
         -> (logits (B,C,V) f32, values (B,C) f32, cache).  dense/moe with
         global attention only (the serving path)."""
         cfg = self.cfg
-        if cfg.family not in ("dense", "moe"):
+        if cfg.family not in ("dense", "moe") or "mamba" in cfg.layer_types:
             raise ValueError(
-                f"prefill_step supports dense/moe only, not {cfg.family}; "
-                "other families decode token-by-token"
+                f"prefill_step supports attention-only dense/moe stacks, not "
+                f"{cfg.name}; other models decode token-by-token"
             )
         C = tokens.shape[1]
         pos = jnp.asarray(pos)
         x = layers.embed(params["embedding"], tokens, jnp.dtype(cfg.param_dtype))
-        if "gemma" in cfg.name:
-            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        x = self._scale_embedding(x)
         if cfg.pos_embed == "learned":
             positions = tf._rope_positions(pos, C)
             x = x + layers.learned_pos(params["pos"], positions).astype(x.dtype)
@@ -726,17 +791,13 @@ class Model:
                 )
                 new_cache[f"layer_{i}"] = c2
         x = layers.rms_norm(params["final_norm"], x, cfg.rms_norm_eps)
-        logits = layers.unembed(params["embedding"], x)
-        values = jnp.einsum(
-            "btd,dk->btk", x, params["value_head"]["w"].astype(x.dtype)
-        )[..., 0].astype(jnp.float32)
+        logits, values = self._heads(params, x)
         return logits, values, new_cache
 
     def _embed_decode(self, params, tokens, pos):
         cfg = self.cfg
         x = layers.embed(params["embedding"], tokens, jnp.dtype(cfg.param_dtype))
-        if "gemma" in cfg.name:
-            x = x * jnp.asarray(math.sqrt(cfg.d_model), x.dtype)
+        x = self._scale_embedding(x)
         if cfg.pos_embed == "learned":
             pos = jnp.asarray(pos)
             if pos.ndim == 0:

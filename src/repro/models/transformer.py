@@ -103,6 +103,24 @@ def scan_decode_layers(
 # ---------------------------------------------------------------------------
 
 
+def residual(x: jax.Array, branch: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """x + the branch, the branch scaled by ``cfg.residual_multiplier``
+    where that is not 1 (granite)."""
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
+    return x + branch
+
+
+def scale_queries(q: jax.Array, cfg: ArchConfig) -> jax.Array:
+    """Queries scaled so that the attention paths' 1/sqrt(head_dim) gives
+    the configured softmax scale ``cfg.attention_multiplier`` (granite:
+    1/64 at head width 64, so q/8, exact in bfloat16)."""
+    if not cfg.attention_multiplier:
+        return q
+    s = cfg.attention_multiplier * cfg.head_dim**0.5
+    return q * jnp.asarray(s, q.dtype)
+
+
 def init_attn_layer(b: ParamBuilder, cfg: ArchConfig) -> None:
     dims = attn.AttnDims(cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.head_dim)
     layers.init_rms_norm(b, "attn_norm", cfg.d_model)
@@ -126,6 +144,7 @@ def attn_sublayer(
         rope_theta=theta if theta is not None else cfg.rope_theta,
         eps=cfg.rms_norm_eps,
     )
+    q = scale_queries(q, cfg)
     if window:
         # blocked local attention materializes O(T·2W) probabilities;
         # checkpoint so they are recomputed (transiently) in backward
@@ -143,7 +162,7 @@ def attn_sublayer(
         out = attn.full_attention(
             q, k, v, causal=causal, softcap=cfg.attn_logit_softcap
         )
-    return x + attn.output_project(p["attn"], out)
+    return residual(x, attn.output_project(p["attn"], out), cfg)
 
 
 def _rope_positions(pos: jax.Array | None, width: int = 1) -> jax.Array | None:
@@ -178,6 +197,7 @@ def attn_sublayer_decode(
         rope_theta=theta if theta is not None else cfg.rope_theta,
         eps=cfg.rms_norm_eps,
     )
+    q = scale_queries(q, cfg)
     if block_tables is not None:
         if window or cfg.attn_logit_softcap:
             raise ValueError(
@@ -189,7 +209,8 @@ def attn_sublayer_decode(
             cache["k"], cache["v"], k, v, block_tables, pos
         )
         out = flash_decode(q, kc, vc, pos, block_tables=block_tables)
-        return x + attn.output_project(p["attn"], out), {"k": kc, "v": vc}
+        return residual(x, attn.output_project(p["attn"], out), cfg), {
+            "k": kc, "v": vc}
     S = cache["k"].shape[1]
     if window and S == window:
         kc, vc = griffin.ring_cache_update(cache["k"], cache["v"], k, v, pos, window)
@@ -206,7 +227,8 @@ def attn_sublayer_decode(
             # the decode hot loop: Pallas flash_decode on TPU, its
             # bit-identical jnp oracle elsewhere (kernels/flash_decode)
             out = flash_decode(q, kc, vc, pos, window=window)
-    return x + attn.output_project(p["attn"], out), {"k": kc, "v": vc}
+    return residual(x, attn.output_project(p["attn"], out), cfg), {
+        "k": kc, "v": vc}
 
 
 def attn_sublayer_prefill(
@@ -235,6 +257,7 @@ def attn_sublayer_prefill(
         p["attn"], h, positions=positions, rope_theta=cfg.rope_theta,
         eps=cfg.rms_norm_eps,
     )
+    q = scale_queries(q, cfg)
     if block_tables is not None:
         kc, vc = attn.update_paged_kv_cache(
             cache["k"], cache["v"], k, v, block_tables, pos
@@ -248,7 +271,8 @@ def attn_sublayer_prefill(
     else:
         kc, vc = attn.update_kv_cache_chunk(cache["k"], cache["v"], k, v, pos)
         out = attn.chunk_decode_attention(q, kc, vc, pos)
-    return x + attn.output_project(p["attn"], out), {"k": kc, "v": vc}
+    return residual(x, attn.output_project(p["attn"], out), cfg), {
+        "k": kc, "v": vc}
 
 
 def init_ffn_layer(b: ParamBuilder, cfg: ArchConfig, kind: str) -> None:
@@ -283,8 +307,8 @@ def ffn_sublayer(
         out, aux = moe_lib.moe_ffn(
             p["moe"], h, moe_dims(cfg), impl=moe_impl, mesh=mesh
         )
-        return x + out, aux
-    return x + layers.mlp(p["mlp"], h), jnp.float32(0.0)
+        return residual(x, out, cfg), aux
+    return residual(x, layers.mlp(p["mlp"], h), cfg), jnp.float32(0.0)
 
 
 def init_cross_layer(b: ParamBuilder, cfg: ArchConfig) -> None:

@@ -174,3 +174,77 @@ def test_flash_attention_compiles_for_v5e(one_chip):
         ((1, T, H, h), BF16), ((1, T, K, h), BF16), ((1, T, K, h), BF16),
     )
     assert _kernels(text) == ["flash_attention"]
+
+
+# granite-4.0-h-micro: Mamba-2 at 64 heads of 64 with state 128; GQA of
+# 32 query heads over 8 kv heads of 64
+GB, GH, GP, GN = 64, 64, 64, 128
+
+
+@pytest.mark.parametrize("T", [17, 256])
+def test_ssd_scan_gradient_compiles_for_v5e(one_chip, T):
+    """The learner's gradient through the SSD scan with the Pallas kernel
+    doing the forward: at the LM cell's 17 tokens (one chunk, padded) and
+    at a whole chunk of 256.  The gradient is the jnp chunk scan's VJP, so
+    the kernel stays outside the linearization; the benchmark's
+    ``ssd_scan_roofline`` finds it by its name."""
+    from repro.kernels.ssd_scan import ops
+
+    def grad(x, dt, A, Bm, Cm):
+        def f(*a):
+            y, _ = ops.ssd_scan(*a, chunk=256, impl="pallas")
+            return jnp.sum(y.astype(jnp.float32) ** 2)
+
+        return jax.grad(f, argnums=(0, 1, 2, 3, 4))(x, dt, A, Bm, Cm)
+
+    text = _compiled_text(
+        grad, one_chip,
+        ((GB, T, GH, GP), BF16), ((GB, T, GH), jnp.float32),
+        ((GH,), jnp.float32), ((GB, T, GN), BF16), ((GB, T, GN), BF16),
+    )
+    from bench import common
+
+    reader = common.load_module(
+        common.BENCH / "metrics" / "ssd_scan_roofline.py")
+    assert _kernels(text) == ["ssd_scan"]
+    assert all(re.search(reader.KERNEL, "%" + n) for n in re.findall(
+        r"%(ssd_scan\.\d+) = ", text))
+
+
+def test_flash_decode_compiles_at_granite_heads(one_chip):
+    from repro.kernels.flash_decode.flash_decode import flash_decode_pallas
+
+    text = _compiled_text(
+        flash_decode_pallas, one_chip,
+        ((GB, 1, 32, 64), BF16), ((GB, 16, 8, 64), BF16),
+        ((GB, 16, 8, 64), BF16), ((GB,), jnp.int32),
+    )
+    assert _kernels(text) == ["flash_decode"]
+
+
+def test_granite_learner_attention_compiles_for_v5e(one_chip):
+    """The learner's attention at Granite's heads over the LM cell's 17
+    tokens, forward and backward: the flash-attention custom VJP in jnp,
+    with Granite's 1/64 softmax scale folded into the queries."""
+    import dataclasses
+
+    from repro.configs import granite_4_0_h_micro as granite
+    from repro.models import attention as attn
+    from repro.models import transformer as tf
+
+    cfg = dataclasses.replace(granite.CONFIG, num_layers=1,
+                              layer_types=("attention",))
+
+    def grad(q, k, v):
+        def f(q, k, v):
+            out = attn.full_attention(tf.scale_queries(q, cfg), k, v)
+            return jnp.sum(out.astype(jnp.float32) ** 2)
+
+        return jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+
+    args = [
+        jax.ShapeDtypeStruct(s, BF16, sharding=one_chip)
+        for s in ((GB, 17, 32, 64), (GB, 17, 8, 64), (GB, 17, 8, 64))
+    ]
+    compiled = jax.jit(grad).lower(*args).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 2**30
